@@ -24,13 +24,12 @@ The 'opd' encode stage is backend-pluggable (``backend=``, mirroring the
 filter path's ``filter_backend``; see docs/DESIGN.md §7):
 
   'numpy'       host gather + host bitpack (the reference).
-  'jax'         the remap runs as the ``kernels.merge_remap`` Pallas
-                kernel (tiled table gather, SMEM offsets); packing stays
-                on the host.
-  'jax_packed'  remap fused with bit-packing in-kernel: output SCT
-                columns go to memory already packed and the remapped
-                int32 codes never materialize (``SCT.evs`` unpacks
-                lazily if a reader asks).
+  'jax'         the remap runs on the device as one XLA gather
+                (``kernels.merge_remap.remap_codes``); packing stays on
+                the host.
+  'jax_packed'  remap then the Pallas bit-packing kernel in one jitted
+                program: output SCT columns come back already packed
+                (``SCT.evs`` unpacks lazily if a reader asks).
 
 All three produce bit-identical SCTs (tests/test_compaction_backends.py
 is the differential contract).

@@ -36,6 +36,21 @@ bounds every matching entry (the true-min entry's tile contributes at
 most its value).  The fold is exact per run; cross-run combination must
 happen in value space after one dictionary decode per run.
 
+Division of work on the TPU: every per-tile verdict (skip / evaluate /
+short-circuit) and every closed-form contribution depends only on the
+tile's meta row and the small range / edge tables, so the jitted
+wrappers compute them for all tiles at once in XLA.  The Pallas kernels
+run only the data-dependent part — field extraction, compare and
+reduction over the tile's words — for tiles marked for evaluation, which
+they learn from a scalar-prefetched per-tile word (range base or edge
+row, -1 otherwise).  Per-tile results leave the kernel as one small
+lane vector per tile (``(1, 4, K)`` / ``(1, 1, B)`` blocks), never as
+scalar stores.  Mosaic has no unsigned reductions, so min/max fold over
+the order-preserving signed key ``bitcast(v, int32) ^ INT32_MIN``; SUM
+reads a per-entry weight column that the wrapper gathers in XLA
+(``weights[weight_base + code]``), since Mosaic cannot gather from a
+VMEM table.
+
 Layout notes shared with ``fused_scan``: little-endian fields in uint32
 words (word j holds codes ``j*per .. j*per+per-1``, ``per = 32//width``),
 padding words are 0xFFFFFFFF, a padding tile carries the empty zone
@@ -51,13 +66,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # SMEM placement for meta/range/edge tables (TPU); interpret supports it
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SMEM = {"memory_space": pltpu.SMEM}
-except Exception:  # pragma: no cover - pallas builds without the TPU ext
-    _SMEM = {}
+from repro.kernels.fused_scan import tile_ranges
 
 DEFAULT_BLOCK_ROWS = 8
 LANES = 128
@@ -74,6 +85,9 @@ FLAG_SKIPPED = 0        # zone intersects no range: words never read
 FLAG_EVALUATED = 1      # fields extracted and compared
 FLAG_SHORTCIRCUIT = 2   # closed-form contribution from the zone alone
 
+_INT32_MIN = -2**31
+_INT32_MAX = 2**31 - 1
+
 
 def _entry_index(rows: int):
     """Linear entry-number-per-word grid [rows, 128] (times ``per`` plus
@@ -83,113 +97,71 @@ def _entry_index(rows: int):
     return r * LANES + l
 
 
-def _make_agg_kernel(width: int, n_preds: int, with_sum: bool,
-                     block_rows: int):
+def _fields(words: jax.Array, width: int):
+    fmask = jnp.uint32((1 << width) - 1)
+    return [(words >> jnp.uint32(f * width)) & fmask
+            for f in range(32 // width)]
+
+
+def _lane_vector(values, n: int):
+    """Assemble ``(1, 1)`` values into one ``(1, n)`` lane vector (TPU
+    stores whole vectors, not scalars)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    out = jnp.zeros((1, n), jnp.int32)
+    for j, v in enumerate(values):
+        out = jnp.where(lane == j, v, out)
+    return out
+
+
+def _sum11(x):
+    return jnp.sum(x, axis=(0, 1), keepdims=True)
+
+
+def _make_agg_kernel(width: int, n_preds: int, with_sum: bool):
     per = 32 // width
-    tile_entries = block_rows * LANES * per
 
-    def kernel(meta_ref, ranges_ref, w_ref, wt_ref,
-               cnt_ref, min_ref, max_ref, sum_ref, flag_ref):
-        z_lo = meta_ref[0, 0]
-        z_hi = meta_ref[0, 1]
-        base = meta_ref[0, 2]
-        n_valid = meta_ref[0, 3].astype(jnp.int32)
-        w_base = meta_ref[0, 4].astype(jnp.int32)
-        wsum = meta_ref[0, WSUM_COL]
+    def kernel(tile_base_ref, n_valid_ref, ranges_ref, w_ref, *refs):
+        wt_ref, out_ref = refs if with_sum else (None, refs[0])
+        i = pl.program_id(0)
+        base = tile_base_ref[i]              # -1: not evaluated here
 
-        any_hit = jnp.zeros((), jnp.bool_)
-        # closed form needs z_lo >= 1 (tombstones pack as 0 and would be
-        # counted) and every intersecting range to CONTAIN the zone.
-        all_closed = z_lo >= jnp.uint32(1)
-        for k in range(n_preds):  # static unroll; ranges live in SMEM
-            lo = ranges_ref[base + k, 0]
-            hi = ranges_ref[base + k, 1]
-            inter = jnp.logical_and(lo <= hi,
-                                    jnp.logical_and(lo <= z_hi, hi >= z_lo))
-            contained = jnp.logical_and(inter,
-                                        jnp.logical_and(lo <= z_lo,
-                                                        z_hi <= hi))
-            any_hit = jnp.logical_or(any_hit, inter)
-            all_closed = jnp.logical_and(
-                all_closed, jnp.logical_or(jnp.logical_not(inter), contained))
-        if with_sum:
-            # SUM's closed form is the tile's exact weight total (meta
-            # col WSUM_COL, from the per-block zone-map weight sums);
-            # the sentinel marks tiles whose total is unknown.
-            all_closed = jnp.logical_and(
-                all_closed, wsum != jnp.uint32(WSUM_SENTINEL))
-        shortcut = jnp.logical_and(any_hit, all_closed)
-
-        @pl.when(shortcut)
-        def _closed_form():
-            # every real entry of the tile matches each intersecting
-            # range; z_lo / z_hi are attained within this run (see
-            # module docstring), so they are valid min/max partials —
-            # and the tile weight total IS the SUM contribution.
-            for k in range(n_preds):
-                lo = ranges_ref[base + k, 0]
-                hi = ranges_ref[base + k, 1]
-                inter = jnp.logical_and(
-                    lo <= hi, jnp.logical_and(lo <= z_hi, hi >= z_lo))
-                cnt_ref[0, k] = jnp.where(inter, n_valid, 0)
-                min_ref[0, k] = jnp.where(inter, z_lo,
-                                          jnp.uint32(MIN_SENTINEL))
-                max_ref[0, k] = jnp.where(inter, z_hi, jnp.uint32(0))
-                if with_sum:
-                    sum_ref[0, k] = jnp.where(inter, wsum.astype(jnp.int32),
-                                              jnp.int32(0))
-                else:
-                    sum_ref[0, k] = jnp.int32(0)
-
-        @pl.when(jnp.logical_and(any_hit, jnp.logical_not(shortcut)))
+        @pl.when(base >= 0)
         def _evaluate():
-            fmask = jnp.uint32((1 << width) - 1)
             w = w_ref[...]                                # [rows, 128]
-            widx = _entry_index(w.shape[0])               # word number
-            if with_sum:
-                wtab = wt_ref[...].reshape(-1)            # flat int32 weights
-            cnts = [jnp.zeros((), jnp.int32) for _ in range(n_preds)]
-            mins = [jnp.uint32(MIN_SENTINEL) for _ in range(n_preds)]
-            maxs = [jnp.uint32(0) for _ in range(n_preds)]
-            sums = [jnp.zeros((), jnp.int32) for _ in range(n_preds)]
-            for f in range(per):  # static unroll: per in {1,2,4,8,16,32}
-                v = (w >> jnp.uint32(f * width)) & fmask  # extracted ONCE
-                valid = (widx * per + f) < n_valid        # padding guard
+            widx = _entry_index(w.shape[0]) * per         # entry of field 0
+            n_valid = n_valid_ref[i]
+            cnt = [jnp.zeros(w.shape, jnp.int32) for _ in range(n_preds)]
+            mn = [jnp.full(w.shape, _INT32_MAX, jnp.int32)
+                  for _ in range(n_preds)]
+            mx = [jnp.full(w.shape, _INT32_MIN, jnp.int32)
+                  for _ in range(n_preds)]
+            sm = [jnp.zeros(w.shape, jnp.int32) for _ in range(n_preds)]
+            for f, v in enumerate(_fields(w, width)):     # extracted ONCE
+                valid = (widx + f) < n_valid              # padding guard
+                key = jax.lax.bitcast_convert_type(v, jnp.int32) ^ _INT32_MIN
                 for k in range(n_preds):                  # reused K times
-                    lo = ranges_ref[base + k, 0]
-                    hi = ranges_ref[base + k, 1]
-                    p = jnp.logical_and(valid,
-                                        jnp.logical_and(v >= lo, v <= hi))
-                    cnts[k] = cnts[k] + jnp.sum(p.astype(jnp.int32))
-                    mins[k] = jnp.minimum(mins[k], jnp.min(
-                        jnp.where(p, v, jnp.uint32(MIN_SENTINEL))))
-                    maxs[k] = jnp.maximum(maxs[k], jnp.max(
-                        jnp.where(p, v, jnp.uint32(0))))
+                    lo = ranges_ref[2 * (base + k)]
+                    hi = ranges_ref[2 * (base + k) + 1]
+                    p = valid & (v >= lo) & (v <= hi)
+                    cnt[k] = cnt[k] + p.astype(jnp.int32)
+                    mn[k] = jnp.minimum(mn[k], jnp.where(p, key, _INT32_MAX))
+                    mx[k] = jnp.maximum(mx[k], jnp.where(p, key, _INT32_MIN))
                     if with_sum:
-                        # dictionary gather: weight of code v (planned
-                        # ranges never exceed the dictionary, so the
-                        # index stays inside this SCT's table slice)
-                        idx = jnp.where(p, w_base + v.astype(jnp.int32), 0)
-                        wt = jnp.take(wtab, idx, axis=0)
-                        sums[k] = sums[k] + jnp.sum(
-                            jnp.where(p, wt, jnp.int32(0)))
-            for k in range(n_preds):
-                cnt_ref[0, k] = cnts[k]
-                min_ref[0, k] = mins[k]
-                max_ref[0, k] = maxs[k]
-                sum_ref[0, k] = sums[k]
+                        sm[k] = sm[k] + jnp.where(p, wt_ref[f], 0)
+            # rows (count, min, max, sum); min/max back to raw uint32 bits
+            rows = [
+                _lane_vector([_sum11(c) for c in cnt], n_preds),
+                _lane_vector([jnp.min(m, axis=(0, 1), keepdims=True)
+                              ^ _INT32_MIN for m in mn], n_preds),
+                _lane_vector([jnp.max(m, axis=(0, 1), keepdims=True)
+                              ^ _INT32_MIN for m in mx], n_preds),
+                _lane_vector([_sum11(m) for m in sm], n_preds),
+            ]
+            out_ref[0] = jnp.concatenate(rows, axis=0)
 
-        @pl.when(jnp.logical_not(any_hit))
-        def _skip():
-            for k in range(n_preds):
-                cnt_ref[0, k] = jnp.int32(0)
-                min_ref[0, k] = jnp.uint32(MIN_SENTINEL)
-                max_ref[0, k] = jnp.uint32(0)
-                sum_ref[0, k] = jnp.int32(0)
-
-        flag_ref[0, 0] = jnp.where(
-            shortcut, jnp.int32(FLAG_SHORTCIRCUIT),
-            any_hit.astype(jnp.int32))
+        @pl.when(base < 0)
+        def _idle():
+            out_ref[...] = jnp.zeros_like(out_ref)
 
     return kernel
 
@@ -220,95 +192,97 @@ def fused_zone_agg_2d(
         (words.shape, meta.shape, block_rows)
     assert meta.shape[1] == AGG_META_COLS and ranges.shape[1] == 2
     assert weights.shape[1] == LANES
-    t_rows = weights.shape[0]
-    grid = (n_tiles,)
     meta = jnp.asarray(meta, jnp.uint32)
     ranges = jnp.asarray(ranges, jnp.uint32)
-    weights = jnp.asarray(weights, jnp.int32)
-    return pl.pallas_call(
-        _make_agg_kernel(width, n_preds, with_sum, block_rows),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, AGG_META_COLS), lambda i: (i, 0), **_SMEM),
-            pl.BlockSpec(ranges.shape, lambda i: (0, 0), **_SMEM),
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((t_rows, LANES), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n_preds), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_preds), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_preds), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_preds), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, n_preds), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, n_preds), jnp.uint32),
-            jax.ShapeDtypeStruct((n_tiles, n_preds), jnp.uint32),
-            jax.ShapeDtypeStruct((n_tiles, n_preds), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, 1), jnp.int32),
-        ],
+    z_lo, z_hi = meta[:, 0:1], meta[:, 1:2]
+    n_valid = meta[:, 3].astype(jnp.int32)
+    wsum = meta[:, WSUM_COL:WSUM_COL + 1]
+
+    # per-tile verdicts: the closed form needs z_lo >= 1 (tombstones
+    # pack as 0 and would be counted) and every intersecting range to
+    # CONTAIN the zone; SUM's closed form is the tile's exact weight
+    # total (meta col WSUM_COL), absent when it holds the sentinel.
+    lo, hi, inter = tile_ranges(meta, ranges, n_preds)
+    contained = inter & (lo <= z_lo) & (z_hi <= hi)
+    any_hit = inter.any(axis=1)
+    all_closed = (z_lo[:, 0] >= 1) & (contained | ~inter).all(axis=1)
+    if with_sum:
+        all_closed &= wsum[:, 0] != jnp.uint32(WSUM_SENTINEL)
+    shortcut = any_hit & all_closed
+    evaluate = any_hit & ~shortcut
+    tile_base = jnp.where(evaluate, meta[:, 2].astype(jnp.int32), -1)
+
+    operands = [tile_base, n_valid, ranges.reshape(-1), words]
+    in_specs = [pl.BlockSpec((block_rows, LANES), lambda i, *_: (i, 0))]
+    if with_sum:
+        # dictionary gather per entry: weight of its code (planned ranges
+        # never exceed the dictionary, so matching entries index inside
+        # their SCT's slice; the rest are clipped and masked in-kernel)
+        w_base = jnp.repeat(meta[:, 4].astype(jnp.int32), block_rows)
+        idx = jnp.stack([w_base[:, None] + v.astype(jnp.int32)
+                         for v in _fields(words, width)])
+        operands.append(jnp.take(jnp.asarray(weights, jnp.int32).reshape(-1),
+                                 idx, mode="clip"))
+        in_specs.append(pl.BlockSpec((32 // width, block_rows, LANES),
+                                     lambda i, *_: (0, i, 0)))
+    part = pl.pallas_call(
+        _make_agg_kernel(width, n_preds, with_sum),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_tiles,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 4, n_preds), lambda i, *_: (i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, 4, n_preds), jnp.int32),
         interpret=interpret,
-    )(meta, ranges, words, weights)
+    )(*operands)
+
+    # every real entry of a short-circuited tile matches each
+    # intersecting range; z_lo / z_hi are attained within this run (see
+    # module docstring), so they are valid min/max partials — and the
+    # tile weight total IS the SUM contribution.
+    sc = shortcut[:, None]
+    ev = evaluate[:, None]
+    as_u32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.uint32)
+    closed_sum = wsum.astype(jnp.int32) if with_sum else 0
+    counts = jnp.where(ev, part[:, 0], jnp.where(sc & inter, n_valid[:, None], 0))
+    mins = jnp.where(ev, as_u32(part[:, 1]),
+                     jnp.where(sc & inter, z_lo, jnp.uint32(MIN_SENTINEL)))
+    maxs = jnp.where(ev, as_u32(part[:, 2]),
+                     jnp.where(sc & inter, z_hi, jnp.uint32(0)))
+    sums = jnp.where(ev, part[:, 3], jnp.where(sc & inter, closed_sum, 0))
+    flags = jnp.where(shortcut, FLAG_SHORTCIRCUIT, any_hit.astype(jnp.int32))
+    return counts, mins, maxs, sums, flags.reshape(-1, 1)
 
 
-def _make_hist_kernel(width: int, n_bins: int, block_rows: int):
+def _make_hist_kernel(width: int, n_bins: int):
     per = 32 // width
     n_edges = n_bins + 1
 
-    def kernel(meta_ref, edges_ref, w_ref, hist_ref, flag_ref):
-        z_lo = meta_ref[0, 0]
-        z_hi = meta_ref[0, 1]
-        seg = meta_ref[0, 2]
-        n_valid = meta_ref[0, 3].astype(jnp.int32)
+    def kernel(tile_seg_ref, n_valid_ref, edges_ref, w_ref, hist_ref):
+        i = pl.program_id(0)
+        seg = tile_seg_ref[i]               # -1: closed form / empty tile
 
-        # how many edges sit at or below each zone bound (static unroll,
-        # edges in SMEM).  Equal counts mean no edge crosses the zone:
-        # every real entry falls in the SAME bin.
-        n_le_lo = jnp.zeros((), jnp.int32)
-        n_le_hi = jnp.zeros((), jnp.int32)
-        for e in range(n_edges):
-            edge = edges_ref[seg, e]
-            n_le_lo = n_le_lo + (edge <= z_lo).astype(jnp.int32)
-            n_le_hi = n_le_hi + (edge <= z_hi).astype(jnp.int32)
-        same_bin = n_le_lo == n_le_hi
-        # zone entirely outside [e_0, e_B): nothing to count
-        outside = jnp.logical_or(z_hi < edges_ref[seg, 0],
-                                 z_lo >= edges_ref[seg, n_bins])
-        empty = jnp.logical_or(outside, n_valid == 0)
-        closed = jnp.logical_or(
-            empty,
-            jnp.logical_and(same_bin, z_lo >= jnp.uint32(1)))
-
-        @pl.when(closed)
-        def _closed_form():
-            # all n_valid entries land in the bin holding z_lo (edge
-            # counts locate it without reading a word); tombstone-free is
-            # guaranteed by z_lo >= 1
-            bstar = n_le_lo - 1
-            for b in range(n_bins):
-                take = jnp.logical_and(jnp.logical_not(empty), bstar == b)
-                hist_ref[0, b] = jnp.where(take, n_valid, 0)
-            flag_ref[0, 0] = jnp.where(empty, jnp.int32(FLAG_SKIPPED),
-                                       jnp.int32(FLAG_SHORTCIRCUIT))
-
-        @pl.when(jnp.logical_not(closed))
+        @pl.when(seg >= 0)
         def _evaluate():
+            # rank counting: ge[e] = #(valid entries >= edges[e]);
+            # hist[b] = ge[b] - ge[b+1] (no scatter required)
             w = w_ref[...]
-            widx = _entry_index(w.shape[0])
-            # rank counting: cnt_ge[e] = #(valid entries >= edges[e]);
-            # hist[b] = cnt_ge[b] - cnt_ge[b+1] (no scatter required)
-            ge = [jnp.zeros((), jnp.int32) for _ in range(n_edges)]
-            fmask = jnp.uint32((1 << width) - 1)
-            for f in range(per):  # static unroll
-                v = (w >> jnp.uint32(f * width)) & fmask
-                valid = (widx * per + f) < n_valid
+            widx = _entry_index(w.shape[0]) * per
+            n_valid = n_valid_ref[i]
+            ge = [jnp.zeros(w.shape, jnp.int32) for _ in range(n_edges)]
+            for f, v in enumerate(_fields(w, width)):
+                valid = (widx + f) < n_valid
                 for e in range(n_edges):
-                    p = jnp.logical_and(valid, v >= edges_ref[seg, e])
-                    ge[e] = ge[e] + jnp.sum(p.astype(jnp.int32))
-            for b in range(n_bins):
-                hist_ref[0, b] = ge[b] - ge[b + 1]
-            flag_ref[0, 0] = jnp.int32(FLAG_EVALUATED)
+                    edge = edges_ref[seg * n_edges + e]
+                    ge[e] = ge[e] + (valid & (v >= edge)).astype(jnp.int32)
+            ge = [_sum11(g) for g in ge]
+            hist_ref[0] = _lane_vector(
+                [ge[b] - ge[b + 1] for b in range(n_bins)], n_bins)
+
+        @pl.when(seg < 0)
+        def _idle():
+            hist_ref[...] = jnp.zeros_like(hist_ref)
 
     return kernel
 
@@ -337,25 +311,42 @@ def zone_histogram_2d(
         (words.shape, meta.shape, block_rows)
     assert meta.shape[1] == AGG_META_COLS
     assert edges.shape[1] == n_bins + 1 and n_bins <= MAX_BINS, edges.shape
-    n_segs = edges.shape[0]
-    grid = (n_tiles,)
     meta = jnp.asarray(meta, jnp.uint32)
     edges = jnp.asarray(edges, jnp.uint32)
-    return pl.pallas_call(
-        _make_hist_kernel(width, n_bins, block_rows),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, AGG_META_COLS), lambda i: (i, 0), **_SMEM),
-            pl.BlockSpec((n_segs, n_bins + 1), lambda i: (0, 0), **_SMEM),
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n_bins), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, n_bins), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, 1), jnp.int32),
-        ],
+    z_lo, z_hi = meta[:, 0:1], meta[:, 1:2]
+    seg = meta[:, 2].astype(jnp.int32)
+    n_valid = meta[:, 3].astype(jnp.int32)
+
+    # how many edges sit at or below each zone bound: equal counts mean
+    # no edge crosses the zone, so every real entry falls in the SAME
+    # bin (tombstone-free guaranteed by z_lo >= 1)
+    e = edges[seg]                                        # [T, B+1]
+    n_le_lo = (e <= z_lo).sum(axis=1)
+    n_le_hi = (e <= z_hi).sum(axis=1)
+    # zone entirely outside [e_0, e_B): nothing to count
+    outside = (z_hi[:, 0] < e[:, 0]) | (z_lo[:, 0] >= e[:, n_bins])
+    empty = outside | (n_valid == 0)
+    closed = empty | ((n_le_lo == n_le_hi) & (z_lo[:, 0] >= 1))
+    tile_seg = jnp.where(closed, -1, seg)
+
+    part = pl.pallas_call(
+        _make_hist_kernel(width, n_bins),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_tiles,),
+            in_specs=[pl.BlockSpec((block_rows, LANES),
+                                   lambda i, *_: (i, 0))],
+            out_specs=pl.BlockSpec((1, 1, n_bins), lambda i, *_: (i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, 1, n_bins), jnp.int32),
         interpret=interpret,
-    )(meta, edges, words)
+    )(tile_seg, n_valid, edges.reshape(-1), words)
+
+    # closed form: all n_valid entries land in the bin holding z_lo
+    bins = jnp.arange(n_bins, dtype=jnp.int32)[None, :]
+    take = ~empty[:, None] & (bins == (n_le_lo - 1)[:, None])
+    hist = jnp.where(closed[:, None], jnp.where(take, n_valid[:, None], 0),
+                     part[:, 0])
+    flags = jnp.where(closed, jnp.where(empty, FLAG_SKIPPED,
+                                        FLAG_SHORTCIRCUIT), FLAG_EVALUATED)
+    return hist, flags.astype(jnp.int32).reshape(-1, 1)

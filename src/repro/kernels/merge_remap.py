@@ -1,4 +1,4 @@
-"""Pallas TPU kernels: compaction-time code remap (Algorithm 1 line 9).
+"""Compaction-time code remap (Algorithm 1 line 9).
 
 After ``OPD.merge_subset_flat`` rebuilds an output SCT's dictionary, every
 surviving entry must be rewritten from its *old* code to its position in
@@ -8,20 +8,15 @@ and a per-source base-offset vector, entry i maps as
 
     ev'[i] = flat[ ev[i] + offset[src[i]] ]        (ev < 0 stays dead)
 
-Two kernels implement this over (block_rows, 128) VMEM tiles:
+The gather is an XLA gather (Mosaic cannot gather from a VMEM table);
+the remapped int32 codes feed straight into the jitted program that
+consumes them:
 
-* ``remap_codes_2d`` — plain remap: int32 codes in, int32 codes out,
-  dead entries (-1 sources: tombstones / dropped) preserved as -1.
-* ``remap_pack_codes_3d`` — the ``jax_packed`` backend: remap fused with
-  k-bit packing (same sublane-axis layout as ``bitpack.pack_codes_3d``),
-  so the remapped int32 codes live only in vector registers and the
-  output column goes to memory already bit-packed.
-
-The offset vector sits in SMEM and is applied by a static select-unroll
-over the (few) input SCTs — no gather needed for it.  The flat remap
-table is small (sum of input dictionary sizes, the paper's D_i terms) and
-rides along in VMEM whole; the per-entry gather is the one dynamic
-access, expressed as ``jnp.take`` on the tile.
+* ``remap_codes`` — plain remap: int32 codes in, int32 codes out, dead
+  entries (-1 sources: tombstones / dropped) preserved as -1.
+* ``remap_pack_codes_3d`` — the ``jax_packed`` backend: remap, then
+  k-bit pack with the ``bitpack.pack_codes_3d`` Pallas kernel, in one
+  jitted program, so the output column leaves the device bit-packed.
 """
 
 from __future__ import annotations
@@ -30,124 +25,37 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-try:  # SMEM placement for the offset table (TPU); interpret mode supports it
-    from jax.experimental.pallas import tpu as pltpu
+from repro.kernels import bitpack as _bitpack
 
-    _SMEM = {"memory_space": pltpu.SMEM}
-except Exception:  # pragma: no cover - pallas builds without the TPU ext
-    _SMEM = {}
-
-LANES = 128
 DEFAULT_BLOCK_ROWS = 128
 
 
-def _apply_offsets(off_ref, src, n_src):
-    """offset[src] via static select-unroll (n_src = number of input SCTs,
-    small by construction — compactions merge a handful of files)."""
-    off = jnp.zeros_like(src)
-    for i in range(n_src):
-        off = jnp.where(src == i, off_ref[i, 0], off)
-    return off
-
-
-def _gather(table, live, ev, off):
-    idx = jnp.where(live, ev + off, 0)
-    return jnp.take(table, idx, axis=0)
-
-
-def _remap_kernel(n_src: int):
-    def kernel(off_ref, table_ref, ev_ref, src_ref, out_ref):
-        table = table_ref[...].reshape(-1)            # [T * 128] flat remap
-        ev = ev_ref[...]                              # [rows, 128]; -1 = dead
-        src = src_ref[...]                            # [rows, 128]
-        live = ev >= 0
-        off = _apply_offsets(off_ref, src, n_src)
-        out_ref[...] = jnp.where(live, _gather(table, live, ev, off), -1)
-
-    return kernel
-
-
-def _remap_pack_kernel(n_src: int, width: int):
-    per = 32 // width
-
-    def kernel(off_ref, table_ref, ev_ref, src_ref, out_ref):
-        table = table_ref[...].reshape(-1)
-        acc = jnp.zeros((ev_ref.shape[0], LANES), jnp.uint32)
-        for k in range(per):  # static unroll: per in {1,2,4,8,16,32}
-            ev = ev_ref[:, k, :]
-            src = src_ref[:, k, :]
-            live = ev >= 0
-            off = _apply_offsets(off_ref, src, n_src)
-            new = _gather(table, live, ev, off)
-            # dead entries and unused-code lookups (table holds -1 there)
-            # pack as 0 — bit-identical to the numpy path's
-            # bitpack(clip(evs, 0)); padding rows enter as ev == -1.
-            code = jnp.maximum(jnp.where(live, new, 0), 0).astype(jnp.uint32)
-            acc = acc | (code << jnp.uint32(k * width))
-        out_ref[...] = acc
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def remap_codes_2d(
-    evs: jax.Array,      # int32 [rows, 128]; -1 = dead entry
-    srcs: jax.Array,     # int32 [rows, 128]; source SCT id per entry
-    table: jax.Array,    # int32 [t_rows, 128]; flat remap, zero-padded
-    offsets: jax.Array,  # int32 [n_src, 1]; base offset of source i in table
-    block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+@jax.jit
+def remap_codes(
+    evs: jax.Array,      # int32 [...]; -1 = dead entry
+    srcs: jax.Array,     # int32 [...]; source SCT id per entry
+    table: jax.Array,    # int32 [T]; flat remap, -1 at unused codes
+    offsets: jax.Array,  # int32 [n_src]; base offset of source i in table
 ):
-    rows = evs.shape[0]
-    n_src = offsets.shape[0]
-    t_rows = table.shape[0]
-    assert evs.shape == srcs.shape == (rows, LANES), (evs.shape, srcs.shape)
-    assert rows % block_rows == 0, (rows, block_rows)
-    grid = (rows // block_rows,)
-    return pl.pallas_call(
-        _remap_kernel(n_src),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n_src, 1), lambda i: (0, 0), **_SMEM),
-            pl.BlockSpec((t_rows, LANES), lambda i: (0, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-        interpret=interpret,
-    )(offsets, table, evs, srcs)
+    live = evs >= 0
+    idx = jnp.where(live, evs + offsets[srcs], 0)
+    return jnp.where(live, table[idx], -1)
 
 
 @functools.partial(jax.jit, static_argnames=("width", "block_rows", "interpret"))
 def remap_pack_codes_3d(
     evs: jax.Array,      # int32 [M, per, 128]; -1 = dead entry
     srcs: jax.Array,     # int32 [M, per, 128]
-    table: jax.Array,    # int32 [t_rows, 128]
-    offsets: jax.Array,  # int32 [n_src, 1]
+    table: jax.Array,    # int32 [T]
+    offsets: jax.Array,  # int32 [n_src]
     width: int = 8,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     interpret: bool = True,
 ):
-    per = 32 // width
-    M = evs.shape[0]
-    n_src = offsets.shape[0]
-    t_rows = table.shape[0]
-    assert evs.shape == srcs.shape == (M, per, LANES), (evs.shape, srcs.shape)
-    assert M % block_rows == 0, (M, block_rows)
-    grid = (M // block_rows,)
-    return pl.pallas_call(
-        _remap_pack_kernel(n_src, width),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n_src, 1), lambda i: (0, 0), **_SMEM),
-            pl.BlockSpec((t_rows, LANES), lambda i: (0, 0)),
-            pl.BlockSpec((block_rows, per, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_rows, per, LANES), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, LANES), jnp.uint32),
-        interpret=interpret,
-    )(offsets, table, evs, srcs)
+    # dead entries and unused-code lookups (table holds -1 there) pack as
+    # 0 — bit-identical to the numpy path's bitpack(clip(evs, 0));
+    # padding rows enter as ev == -1.
+    codes = jnp.maximum(remap_codes(evs, srcs, table, offsets), 0)
+    return _bitpack.pack_codes_3d(codes, width, block_rows=block_rows,
+                                  interpret=interpret)

@@ -25,13 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # SMEM placement for the range table (TPU); interpret mode supports it
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SMEM = {"memory_space": pltpu.SMEM}
-except Exception:  # pragma: no cover - pallas builds without the TPU ext
-    _SMEM = {}
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_ROWS = 256
 LANES = 128
@@ -78,7 +72,8 @@ def multi_range_filter_packed_2d(
         _make_kernel(width, n_preds),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((n_preds, 2), lambda i: (0, 0), **_SMEM),
+            pl.BlockSpec((n_preds, 2), lambda i: (0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         ],
         out_specs=[

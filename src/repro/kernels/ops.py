@@ -1,10 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles shape padding to tile boundaries, 1D<->2D lane reshaping, and
-interpret-mode dispatch: on this CPU-only container every kernel runs
-with ``interpret=True`` (the kernel body executes in Python for
-correctness validation); on a real TPU backend the same calls compile to
-Mosaic.  ``INTERPRET`` flips automatically.
+interpret-mode dispatch.  On a TPU backend every kernel compiles to
+Mosaic.  On the ``cpu`` backend (``JAX_PLATFORMS=cpu``, how the tests
+and examples run) every kernel runs with ``interpret=True``: the kernel
+body executes through the Pallas interpreter, which validates results
+but says nothing about speed.  Any other backend is refused at import,
+so a run that lost its TPU cannot slip into interpret mode unnoticed.
 """
 
 from __future__ import annotations
@@ -25,7 +27,12 @@ from repro.kernels import opd_filter as _opd_filter
 from repro.kernels import packed_filter as _packed_filter
 from repro.kernels import ssm_scan as _ssm
 
-INTERPRET = jax.default_backend() != "tpu"
+_BACKEND = jax.default_backend()
+if _BACKEND not in ("tpu", "cpu"):
+    raise RuntimeError(
+        f"Pallas kernels compile for 'tpu' and interpret on 'cpu'; "
+        f"backend {_BACKEND!r} is neither")
+INTERPRET = _BACKEND == "cpu"
 LANES = 128
 
 
@@ -487,19 +494,17 @@ def _pad_rows_pow2(x: jax.Array, unit: int, fill) -> jax.Array:
 
 
 def _remap_operands(table, offsets):
-    """Shape the flat remap table + per-source offsets for the kernels:
-    table zero-padded to a power-of-two (t_rows, 128) VMEM block (>= 1
-    row so the dead-entry placeholder gather stays in bounds), offsets
-    as (n_src, 1) SMEM."""
+    """Flat remap table zero-padded to a power-of-two multiple of 128
+    (>= 1 row, so the dead-entry placeholder gather stays in bounds)
+    and the per-source offsets as int32 [n_src]."""
     n_src = len(offsets) - 1
-    tbl = jnp.asarray(np.asarray(table, np.int32))
-    tbl = _pad_rows_pow2(tbl, LANES, 0).reshape(-1, LANES)
-    offs = jnp.asarray(np.asarray(offsets[:n_src], np.int32).reshape(n_src, 1))
+    tbl = _pad_rows_pow2(jnp.asarray(np.asarray(table, np.int32)), LANES, 0)
+    offs = jnp.asarray(np.asarray(offsets[:n_src], np.int32))
     return tbl, offs
 
 
-def remap_codes(evs, srcs, table, offsets, block_rows: int = 128) -> np.ndarray:
-    """Flattened <src, ev> -> ev' remap (Algorithm 1 line 9) as one tiled
+def remap_codes(evs, srcs, table, offsets) -> np.ndarray:
+    """Flattened <src, ev> -> ev' remap (Algorithm 1 line 9) as one
     table gather.  evs int32 [n] (-1 = dead), srcs int32 [n],
     table int32 [sum D_i], offsets [n_src + 1]; returns int32 [n] with
     dead entries preserved as -1."""
@@ -508,14 +513,10 @@ def remap_codes(evs, srcs, table, offsets, block_rows: int = 128) -> np.ndarray:
     if n == 0:
         return np.zeros(0, np.int32)
     tbl, offs = _remap_operands(table, offsets)
-    ev2 = _pad_rows_pow2(evs, LANES, -1).reshape(-1, LANES)
-    src2 = _pad_rows_pow2(jnp.asarray(srcs, jnp.int32),
-                          LANES, 0).reshape(-1, LANES)
-    out = _merge_remap.remap_codes_2d(ev2, src2, tbl, offs,
-                                      block_rows=min(block_rows,
-                                                     ev2.shape[0]),
-                                      interpret=INTERPRET)
-    return np.asarray(out).reshape(-1)[:n]
+    out = _merge_remap.remap_codes(
+        _pad_rows_pow2(evs, LANES, -1),
+        _pad_rows_pow2(jnp.asarray(srcs, jnp.int32), LANES, 0), tbl, offs)
+    return np.asarray(out)[:n]
 
 
 def remap_pack_codes(evs, srcs, table, offsets, width: int,
@@ -523,7 +524,7 @@ def remap_pack_codes(evs, srcs, table, offsets, width: int,
     """Fused remap + k-bit pack ('jax_packed' compaction backend): returns
     uint32 words [ceil(n / (32/width))] in the same linear layout as
     ``core.sct.bitpack`` — word j holds entries j*per .. j*per+per-1, and
-    dead entries pack as 0.  Remapped int32 codes never reach memory."""
+    dead entries pack as 0.  Only the packed words leave the device."""
     per = 32 // width
     evs = jnp.asarray(evs, jnp.int32)
     n = evs.shape[0]

@@ -204,6 +204,55 @@ def test_hist_kernel_matches_ref(width):
     np.testing.assert_array_equal(np.asarray(got_f), want_f)
 
 
+MANY_TILES = 2304  # above the 1,024-tile wall of whole-array SMEM meta
+
+
+@pytest.mark.parametrize("kernel", ["agg", "agg_sum", "hist"])
+def test_agg_kernels_match_ref_many_tiles(kernel):
+    """Kernel == oracle over a level launch of MANY_TILES tiles (per-tile
+    meta now travels as 1-D scalar-prefetch tables)."""
+    rng = np.random.default_rng(len(kernel))
+    width, block_rows, epb = 16, agg_scan.DEFAULT_BLOCK_ROWS, 512
+    tile_entries = block_rows * agg_scan.LANES * (32 // width)
+    n = MANY_TILES * tile_entries - 777   # partial last tile
+    maxv = 1 << 12
+    codes = rng.integers(0, maxv, n)
+    codes[: n // 2].sort()                # clustered half: short-circuits
+    codes = codes.astype(np.int32)
+    from repro.core.sct import bitpack
+    edges = np.arange(0, n, epb)
+    u = codes.astype(np.uint32)
+    zones = (np.minimum.reduceat(u, edges), np.maximum.reduceat(u, edges), epb)
+    words_all, metas, _w, _t = ops._level_tiles(
+        [bitpack(codes, width)], [n], [zones], width, block_rows,
+        agg_scan.AGG_META_COLS)
+    meta = metas[0]
+    assert meta.shape[0] == MANY_TILES
+    if kernel == "hist":
+        edges_tab = np.asarray([[1, 100, 900, 2000, 4000, maxv]], np.uint32)
+        got = agg_scan.zone_histogram_2d(
+            jnp.asarray(words_all), jnp.asarray(meta), jnp.asarray(edges_tab),
+            width=width, n_bins=5, block_rows=block_rows, interpret=True)
+        want = ref.zone_histogram(words_all, meta, edges_tab, width=width,
+                                  n_bins=5, block_rows=block_rows)
+    else:
+        with_sum = kernel == "agg_sum"
+        ranges = np.asarray([(1, maxv - 1), (1, 0), (300, 2500)], np.uint32)
+        weights = rng.integers(-50, 1000, (maxv // agg_scan.LANES,
+                                           agg_scan.LANES)).astype(np.int32)
+        got = agg_scan.fused_zone_agg_2d(
+            jnp.asarray(words_all), jnp.asarray(meta), jnp.asarray(ranges),
+            jnp.asarray(weights), width=width, n_preds=3, with_sum=with_sum,
+            block_rows=block_rows, interpret=True)
+        want = ref.fused_zone_agg(words_all, meta, ranges, weights,
+                                  width=width, n_preds=3, with_sum=with_sum,
+                                  block_rows=block_rows)
+    flags = np.asarray(got[-1])
+    assert (flags == agg_scan.FLAG_EVALUATED).any()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_level_agg_matches_direct_numpy():
     """ops.fused_level_agg partials == direct numpy over the raw codes
     (count / exact min / exact max / sum per range, per SCT)."""

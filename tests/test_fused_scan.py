@@ -88,6 +88,32 @@ def test_fused_kernel_matches_oracle(width):
     assert int(np.asarray(got_h)[2, 0]) == 0  # the empty-zone tile skipped
 
 
+def test_fused_kernel_matches_oracle_many_tiles():
+    """Kernel == oracle over 2,304 tiles: above the 1,024-tile wall that a
+    whole-array SMEM meta table hit (per-tile range bases now travel as
+    a 1-D scalar-prefetch table)."""
+    rng = np.random.default_rng(2304)
+    width, n_tiles, n_preds = 32, 2304, 3
+    block_rows = fused_scan.DEFAULT_BLOCK_ROWS
+    words = rng.integers(0, 5000, (n_tiles * block_rows, fused_scan.LANES)
+                         ).astype(np.uint32)
+    meta = np.zeros((n_tiles, fused_scan.META_COLS), np.uint32)
+    meta[:, 0] = rng.integers(0, 2500, n_tiles)
+    meta[:, 1] = meta[:, 0] + rng.integers(0, 2500, n_tiles)
+    meta[:, 2] = (np.arange(n_tiles) % 2) * n_preds
+    meta[7] = (*fused_scan.EMPTY_ZONE, 0, 0)
+    ranges = np.asarray([(10, 400), (1, 0), (3000, 4000),
+                         (100, 200), (4500, 4600), (1, 0)], np.uint32)
+    args = (jnp.asarray(words), jnp.asarray(meta), jnp.asarray(ranges))
+    got_b, got_h = fused_scan.fused_zone_filter_2d(
+        *args, width=width, n_preds=n_preds, block_rows=block_rows,
+        interpret=True)
+    exp_b, exp_h = ref.fused_zone_filter(*args, width, n_preds, block_rows)
+    assert 0 < int(np.asarray(got_h).sum()) < n_tiles
+    assert np.array_equal(np.asarray(got_b), np.asarray(exp_b))
+    assert np.array_equal(np.asarray(got_h), np.asarray(exp_h))
+
+
 # --------------------------------------------------------------------------- #
 # ops.fused_level_filter vs the staged multi_filter path
 # --------------------------------------------------------------------------- #
