@@ -30,7 +30,7 @@ block (C_D x F); 'blob' performs random value addressing in blob files.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence, Tuple
+from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -134,7 +134,7 @@ def evaluate_filter_many(
 
     The 'fused' backend additionally batches ACROSS runs: every 'opd'
     run of a level goes through ONE ``kernels.ops.fused_level_filter``
-    launch (zone-gated; see ``_fused_level_masks``), so launch count is
+    launch (zone-gated; see ``_fused_level_bitmaps``), so launch count is
     per level, not per run.
 
     ``value_width`` pins the dtype of empty results.  Without it an
@@ -174,50 +174,37 @@ def evaluate_filter_many(
     cand_vals = [[] for _ in range(n_preds)]
     n_scanned = 0
     with stats.time("filter"):
-        fused_masks = (_fused_level_masks(live_runs, preds, stats)
-                       if backend == "fused" else {})
-        for i, s in enumerate(live_runs):
+        for i, s, masks in _run_masks(live_runs, preds, decoded, stats,
+                                      backend, snap):
             n_scanned += s.n
-            if s.codec == "opd":
-                if backend == "fused":
-                    masks = fused_masks[i]
-                else:
-                    # K x O(log D) planning on the dictionary, then ONE
-                    # column pass evaluating every planned code range.
-                    ranges = [s.opd.code_range(p) for p in preds]
-                    masks = _code_masks_many(s, ranges, backend)
-            else:
-                vals = s.values if s.codec == "plain" else decoded[i]
-                base = ~s.tombs
-                masks = [string_mask(vals, p) & base for p in preds]
-            for q in range(n_preds):
-                mask = masks[q]
-                if snap is not None:
-                    mask = mask & (s.seqnos <= snap)
-                idx = np.nonzero(mask)[0]
-                if idx.shape[0] == 0:
-                    continue
-                cand_keys[q].append(s.keys[idx])
-                cand_seqs[q].append(s.seqnos[idx])
-                if s.codec == "opd":
-                    # O(1) decode: code is the offset into the dictionary
-                    cand_vals[q].append(s.opd.decode(s.evs[idx]))
-                elif s.codec == "plain":
-                    cand_vals[q].append(s.values[idx])
-                else:
-                    cand_vals[q].append(decoded[i][idx])
+            with stats.time("gather"):
+                for q in range(n_preds):
+                    idx = np.nonzero(masks[q])[0]
+                    if idx.shape[0] == 0:
+                        continue
+                    stats.counts["gathered_rows"] += idx.shape[0]
+                    cand_keys[q].append(s.keys[idx])
+                    cand_seqs[q].append(s.seqnos[idx])
+                    if s.codec == "opd":
+                        # O(1) decode: code is the offset into the dictionary
+                        cand_vals[q].append(s.opd.decode(s.evs[idx]))
+                    elif s.codec == "plain":
+                        cand_vals[q].append(s.values[idx])
+                    else:
+                        cand_vals[q].append(decoded[i][idx])
         # memtable stack (newest data) — small, row-oriented scans,
         # walked once per memtable.  Rows shadowed by a newer memtable
         # (or run) are discarded by the seqno merge below, so simply
         # concatenating every memtable's newest-visible rows is correct.
-        mk, ms, mv = _memtable_visible(mems, snap, value_width)
-        if mk.shape[0]:
-            for q, p in enumerate(preds):
-                m = string_mask(mv, p)
-                if m.any():
-                    cand_keys[q].append(mk[m])
-                    cand_seqs[q].append(ms[m])
-                    cand_vals[q].append(mv[m])
+        with stats.time("memtable"):
+            mk, ms, mv = _memtable_visible(mems, snap, value_width)
+            if mk.shape[0]:
+                for q, p in enumerate(preds):
+                    m = string_mask(mv, p)
+                    if m.any():
+                        cand_keys[q].append(mk[m])
+                        cand_seqs[q].append(ms[m])
+                        cand_vals[q].append(mv[m])
 
     # ---- stage: merge (discard stale versions, per predicate) ------------ #
     results = []
@@ -315,7 +302,56 @@ def _code_masks_many(
     raise ValueError(backend)
 
 
-def _fused_level_masks(
+def _run_masks(
+    live_runs: List[SCT], preds: Sequence[Predicate],
+    decoded: List[Optional[np.ndarray]], stats: StageStats, backend: str,
+    snap,
+) -> Iterator[Tuple[int, SCT, List[np.ndarray]]]:
+    """Per live run ``(index, run, K bool masks)``: the entries that match
+    each predicate, are live and are visible at ``snap``.
+
+    Timed inside the caller's ``"filter"`` stage: ``plan`` (code ranges
+    per run and predicate) and ``expand`` (fused bitmaps to per-entry
+    masks, the tombstone and snapshot masks; one block per run).  The
+    'fused' backend evaluates every 'opd' run up front, one launch per
+    level (``_fused_level_bitmaps``)."""
+    fused = (_fused_level_bitmaps(live_runs, preds, stats)
+             if backend == "fused" else {})
+    for i, s in enumerate(live_runs):
+        if s.codec != "opd":
+            vals = s.values if s.codec == "plain" else decoded[i]
+            base = ~s.tombs
+            masks = [string_mask(vals, p) & base for p in preds]
+        elif i not in fused:
+            # K x O(log D) planning on the dictionary, then ONE column
+            # pass evaluating every planned code range
+            with stats.time("plan"):
+                ranges = [s.opd.code_range(p) for p in preds]
+            masks = _code_masks_many(s, ranges, backend)
+        with stats.time("expand"):
+            if i in fused:
+                masks = _expand_bitmaps(s, fused[i], len(preds))
+            if snap is not None:
+                visible = s.seqnos <= snap
+                masks = [m & visible for m in masks]
+        yield i, s, masks
+
+
+def _expand_bitmaps(s: SCT, bitmaps: Optional[np.ndarray],
+                    n_preds: int) -> List[np.ndarray]:
+    """K per-entry masks of one run from its fused-filter bitmaps
+    (None: its level had nothing to evaluate).  Tombstones pack as 0,
+    so they are masked out of the bitmap here."""
+    if bitmaps is None:
+        return [np.zeros(s.n, np.bool_) for _ in range(n_preds)]
+    from repro.kernels import ops as kops
+
+    live = ~s.tombs
+    return [kops.bitmap_to_mask(bitmaps[k], s.code_bits, s.n) & live
+            for k in range(n_preds)]
+
+
+def _fused_level_bitmaps(
     live_runs: List[SCT], preds: Sequence[Predicate], stats: StageStats,
 ) -> dict:
     """The 'fused' backend: plan + evaluate every 'opd' run through the
@@ -329,10 +365,13 @@ def _fused_level_masks(
     dictionaries* still share the launch.  Per-block code zones from
     ``BlockIndex`` gate each tile in-kernel; pruning telemetry lands in
     ``stats.counts`` (``fused_launches``, ``zone_tiles_*``,
-    ``zone_blocks_*``) for the bench reports.
+    ``zone_blocks_*``) for the bench reports, the launch's stages and
+    byte counters in ``stats`` (``kernels.ops.fused_level_filter``).
 
-    Returns {run index -> K bool masks}, bit-identical to the
-    'jax_packed'/'numpy' backends for every run.
+    Returns {run index -> uint32 [K, n_words] bitmaps, or None when no
+    predicate can match anywhere in its level}; ``_expand_bitmaps``
+    turns them into masks bit-identical to the 'jax_packed'/'numpy'
+    backends.
     """
     from repro.kernels import ops as kops
 
@@ -343,36 +382,32 @@ def _fused_level_masks(
     out: dict = {}
     for (_level, width), idxs in sorted(groups.items()):
         ranges_list, zones_list = [], []
-        for i in idxs:
-            s = live_runs[i]
-            rr = [s.opd.code_range(p) for p in preds]
-            # inclusive [lo, hi-1]; lo > hi encodes empty in-kernel
-            ranges_list.append(np.asarray(
-                [(lo, hi - 1) if lo < hi else (1, 0) for lo, hi in rr],
-                np.uint32))
-            b = s.blocks
-            zones_list.append(
-                (b.code_lo, b.code_hi, b.entries_per_block)
-                if b is not None and b.has_zones else None)
+        with stats.time("plan"):
+            for i in idxs:
+                s = live_runs[i]
+                rr = [s.opd.code_range(p) for p in preds]
+                # inclusive [lo, hi-1]; lo > hi encodes empty in-kernel
+                ranges_list.append(np.asarray(
+                    [(lo, hi - 1) if lo < hi else (1, 0) for lo, hi in rr],
+                    np.uint32))
+                b = s.blocks
+                zones_list.append(
+                    (b.code_lo, b.code_hi, b.entries_per_block)
+                    if b is not None and b.has_zones else None)
         if all((r[:, 0] > r[:, 1]).all() for r in ranges_list):
             # no predicate can match anywhere in this level: skip the
             # launch entirely (keeps fused_launches honest)
-            for i in idxs:
-                out[i] = [np.zeros(live_runs[i].n, np.bool_) for _ in preds]
+            out.update((i, None) for i in idxs)
             continue
         bitmaps, info = kops.fused_level_filter(
             [live_runs[i].packed for i in idxs],
             [live_runs[i].n for i in idxs],
-            ranges_list, zones_list, width)
+            ranges_list, zones_list, width, stats=stats)
         stats.counts["fused_launches"] += 1
         for k in ("tiles_total", "tiles_skipped", "blocks_total",
                   "blocks_skipped", "blocks_prunable"):
             stats.counts[f"zone_{k}"] += info[k]
-        for j, i in enumerate(idxs):
-            s = live_runs[i]
-            live = ~s.tombs  # tombstones pack as 0: mask out of bitmap
-            out[i] = [kops.bitmap_to_mask(bitmaps[j][k], width, s.n) & live
-                      for k in range(len(preds))]
+        out.update(zip(idxs, bitmaps))
     return out
 
 

@@ -197,12 +197,12 @@ class LSMTree:
         else:
             raise ValueError(f"unknown maintenance mode {cfg.maintenance!r}")
         # stats
-        self.compaction_stats = StageStats()
-        self.filter_stats = StageStats()
-        self.flush_stats = StageStats()
-        self.lookup_stats = StageStats()
-        self.throttle_stats = StageStats()  # 'slowdown' / 'stop' stages
-        self.agg_stats = StageStats()       # analytics pushdown (repro.query)
+        self.compaction_stats = StageStats("compaction")
+        self.filter_stats = StageStats("filter")
+        self.flush_stats = StageStats("flush")
+        self.lookup_stats = StageStats("lookup")
+        self.throttle_stats = StageStats("throttle")  # 'slowdown' / 'stop' stages
+        self.agg_stats = StageStats("agg")  # analytics pushdown (repro.query)
         self.n_flushes = 0
         self.n_compactions = 0
         self.write_stalls = 0

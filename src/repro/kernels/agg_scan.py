@@ -235,6 +235,7 @@ def fused_zone_agg_2d(
         ),
         out_shape=jax.ShapeDtypeStruct((n_tiles, 4, n_preds), jnp.int32),
         interpret=interpret,
+        name="fused_zone_agg",
     )(*operands)
 
     # every real entry of a short-circuited tile matches each
@@ -340,6 +341,7 @@ def zone_histogram_2d(
         ),
         out_shape=jax.ShapeDtypeStruct((n_tiles, 1, n_bins), jnp.int32),
         interpret=interpret,
+        name="zone_histogram",
     )(tile_seg, n_valid, edges.reshape(-1), words)
 
     # closed form: all n_valid entries land in the bin holding z_lo

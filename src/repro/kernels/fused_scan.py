@@ -130,5 +130,6 @@ def fused_zone_filter_2d(
         ),
         out_shape=jax.ShapeDtypeStruct((n_preds, rows, LANES), jnp.uint32),
         interpret=interpret,
+        name="fused_zone_filter",
     )(tile_base, ranges.reshape(-1), words)
     return bitmaps, hit.astype(jnp.int32).reshape(-1, 1)

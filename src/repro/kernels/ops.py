@@ -11,7 +11,9 @@ so a run that lost its TPU cannot slip into interpret mode unnoticed.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+from collections import defaultdict
 
 import jax
 import jax.numpy as jnp
@@ -108,11 +110,43 @@ def multi_range_filter_packed(words, width: int, ranges,
 
 
 # --------------------------------------------------------------------------- #
+# level-wide launches (fused_scan, agg_scan): upload, run, read back
+# --------------------------------------------------------------------------- #
+class _Untimed:
+    """The ``stats`` of a caller that passes none: stages untimed,
+    counters dropped."""
+
+    def __init__(self) -> None:
+        self.counts = defaultdict(int)
+
+    def time(self, stage: str):
+        return contextlib.nullcontext()
+
+
+def _launch(st, kernel, inputs, **static) -> list:
+    """Run one level-wide kernel in three timed stages of ``st``: upload
+    ``inputs`` (``ops.h2d``), run ``kernel`` until its outputs are ready
+    (``ops.device``) and copy them to the host (``ops.d2h``).  Each stage
+    waits for the one before, so the spans do not overlap.  Counts the
+    bytes each way in ``h2d_bytes`` / ``d2h_bytes``."""
+    with st.time("ops.h2d"):
+        dev = jax.block_until_ready(jax.device_put(inputs))
+    with st.time("ops.device"):
+        outs = jax.block_until_ready(
+            kernel(*dev, interpret=INTERPRET, **static))
+    with st.time("ops.d2h"):
+        host = [np.asarray(o) for o in outs]
+    st.counts["h2d_bytes"] += sum(x.nbytes for x in inputs)
+    st.counts["d2h_bytes"] += sum(x.nbytes for x in host)
+    return host
+
+
+# --------------------------------------------------------------------------- #
 # fused_scan: one zone-gated launch over every SCT of a level
 # --------------------------------------------------------------------------- #
 def fused_level_filter(
     packed_list, n_list, ranges_list, zones_list, width: int,
-    block_rows: int = _fused.DEFAULT_BLOCK_ROWS,
+    block_rows: int = _fused.DEFAULT_BLOCK_ROWS, stats=None,
 ):
     """ONE kernel launch evaluating K code ranges over S packed columns.
 
@@ -133,50 +167,53 @@ def fused_level_filter(
 
     Returns (bitmaps, info): bitmaps[s] is uint32 [K, n_words_s] aligned
     with packed_list[s] (bit-identical to ``multi_range_filter_packed``
-    per SCT); info counts tiles/blocks skipped for StageStats.
+    per SCT); info counts tiles/blocks skipped for StageStats.  The
+    caller's ``stats`` (a ``core.stats.StageStats``) gets the launch's
+    ``ops.*`` stages and byte counters (``_launch``).
     """
+    st = stats if stats is not None else _Untimed()
     per = 32 // width
     tile_words = block_rows * LANES
     tile_entries = tile_words * per
     n_preds = int(np.asarray(ranges_list[0], np.uint32).reshape(-1, 2).shape[0])
-    chunks, metas, seg_words, seg_tiles = [], [], [], []
-    for s_idx, (packed, n, zones) in enumerate(
-            zip(packed_list, n_list, zones_list)):
-        words = np.asarray(packed, np.uint32).reshape(-1)
-        m = words.shape[0]
-        n_tiles = max(1, -(-m // tile_words))
-        pad = np.full(n_tiles * tile_words, 0xFFFFFFFF, np.uint32)
-        pad[:m] = words
-        chunks.append(pad)
-        seg_words.append(m)
-        seg_tiles.append(n_tiles)
-        meta = np.zeros((n_tiles, _fused.META_COLS), np.uint32)
-        meta[:, 2] = s_idx * n_preds
-        if zones is None or m == 0:
-            # no zone map: every tile is a forced hit (full evaluation)
-            meta[:, 0], meta[:, 1] = 0, 0xFFFFFFFF
-        else:
-            code_lo, code_hi, epb = zones
-            for t in range(n_tiles):
-                e0 = t * tile_entries
-                e1 = min(int(n), (t + 1) * tile_entries)
-                if e0 >= e1:  # padding-only tile: always skipped
-                    meta[t, 0], meta[t, 1] = _fused.EMPTY_ZONE
-                    continue
-                b0, b1 = e0 // epb, (e1 - 1) // epb
-                meta[t, 0] = code_lo[b0:b1 + 1].min()
-                meta[t, 1] = code_hi[b0:b1 + 1].max()
-        metas.append(meta)
-    words_all = np.concatenate(chunks).reshape(-1, LANES)
-    meta_all = np.concatenate(metas)
-    ranges_all = np.concatenate(
-        [np.asarray(r, np.uint32).reshape(-1, 2) for r in ranges_list])
-    bitmaps2, hits2 = _fused.fused_zone_filter_2d(
-        jnp.asarray(words_all), jnp.asarray(meta_all), jnp.asarray(ranges_all),
-        width=width, n_preds=n_preds, block_rows=block_rows,
-        interpret=INTERPRET)
-    flat = np.asarray(bitmaps2).reshape(n_preds, -1)
-    hit = np.asarray(hits2).reshape(-1).astype(bool)
+    with st.time("ops.prep"):
+        chunks, metas, seg_words, seg_tiles = [], [], [], []
+        for s_idx, (packed, n, zones) in enumerate(
+                zip(packed_list, n_list, zones_list)):
+            words = np.asarray(packed, np.uint32).reshape(-1)
+            m = words.shape[0]
+            n_tiles = max(1, -(-m // tile_words))
+            pad = np.full(n_tiles * tile_words, 0xFFFFFFFF, np.uint32)
+            pad[:m] = words
+            chunks.append(pad)
+            seg_words.append(m)
+            seg_tiles.append(n_tiles)
+            meta = np.zeros((n_tiles, _fused.META_COLS), np.uint32)
+            meta[:, 2] = s_idx * n_preds
+            if zones is None or m == 0:
+                # no zone map: every tile is a forced hit (full evaluation)
+                meta[:, 0], meta[:, 1] = 0, 0xFFFFFFFF
+            else:
+                code_lo, code_hi, epb = zones
+                for t in range(n_tiles):
+                    e0 = t * tile_entries
+                    e1 = min(int(n), (t + 1) * tile_entries)
+                    if e0 >= e1:  # padding-only tile: always skipped
+                        meta[t, 0], meta[t, 1] = _fused.EMPTY_ZONE
+                        continue
+                    b0, b1 = e0 // epb, (e1 - 1) // epb
+                    meta[t, 0] = code_lo[b0:b1 + 1].min()
+                    meta[t, 1] = code_hi[b0:b1 + 1].max()
+            metas.append(meta)
+        words_all = np.concatenate(chunks).reshape(-1, LANES)
+        meta_all = np.concatenate(metas)
+        ranges_all = np.concatenate(
+            [np.asarray(r, np.uint32).reshape(-1, 2) for r in ranges_list])
+    bitmaps2, hits2 = _launch(
+        st, _fused.fused_zone_filter_2d, [words_all, meta_all, ranges_all],
+        width=width, n_preds=n_preds, block_rows=block_rows)
+    flat = bitmaps2.reshape(n_preds, -1)
+    hit = hits2.reshape(-1).astype(bool)
 
     bitmaps, info = [], {
         "tiles_total": int(hit.shape[0]),
@@ -325,6 +362,7 @@ def _tile_info(flags: np.ndarray) -> dict:
 def fused_level_agg(
     packed_list, n_list, ranges_list, zones_list, width: int,
     weights_list=None, block_rows: int = _fused.DEFAULT_BLOCK_ROWS,
+    stats=None,
 ):
     """ONE launch computing K (count, min, max[, sum]) partials over every
     packed column of a level, folded per SCT on the host.
@@ -340,42 +378,46 @@ def fused_level_agg(
     ``counts``/``sums`` [K] and ``min_code``/``max_code`` [K] (-1 when no
     entry of that SCT matched range k); the min/max fold over tiles is
     exact per SCT (see ``agg_scan`` docstring).  info carries the
-    tiles_{total,skipped,evaluated,shortcircuit} telemetry.
+    tiles_{total,skipped,evaluated,shortcircuit} telemetry.  ``stats``
+    as in ``fused_level_filter``.
     """
+    st = stats if stats is not None else _Untimed()
     n_preds = int(np.asarray(ranges_list[0], np.uint32).reshape(-1, 2).shape[0])
     with_sum = weights_list is not None
-    words_all, metas, _seg_words, seg_tiles = _level_tiles(
-        packed_list, n_list, zones_list, width, block_rows,
-        _agg.AGG_META_COLS)
-    if with_sum:
-        w_off, tabs = 0, []
-        for s_idx, (meta, wts) in enumerate(zip(metas, weights_list)):
-            meta[:, 4] = w_off
-            wts = np.asarray(wts, np.int32).reshape(-1)
-            tabs.append(wts)
-            w_off += wts.shape[0]
-            _tile_weight_sums(meta, packed_list[s_idx], n_list[s_idx],
-                              zones_list[s_idx], wts, width, block_rows)
-        flat = np.concatenate(tabs) if tabs else np.zeros(0, np.int32)
-        pad = -(-max(1, flat.shape[0]) // LANES) * LANES
-        weights = np.zeros(pad, np.int32)
-        weights[:flat.shape[0]] = flat
-        weights = weights.reshape(-1, LANES)
-    else:
-        weights = np.zeros((1, LANES), np.int32)
-    meta_all = np.concatenate(metas)
-    meta_all[:, 2] = np.repeat(np.arange(len(seg_tiles)), seg_tiles) * n_preds
-    ranges_all = np.concatenate(
-        [np.asarray(r, np.uint32).reshape(-1, 2) for r in ranges_list])
-    cnts, mins, maxs, sums, flags = _agg.fused_zone_agg_2d(
-        jnp.asarray(words_all), jnp.asarray(meta_all), jnp.asarray(ranges_all),
-        jnp.asarray(weights), width=width, n_preds=n_preds, with_sum=with_sum,
-        block_rows=block_rows, interpret=INTERPRET)
-    cnts = np.asarray(cnts).astype(np.int64)
-    mins = np.asarray(mins).astype(np.int64)
-    maxs = np.asarray(maxs).astype(np.int64)
-    sums = np.asarray(sums).astype(np.int64)
-    flags = np.asarray(flags).reshape(-1)
+    with st.time("ops.prep"):
+        words_all, metas, _seg_words, seg_tiles = _level_tiles(
+            packed_list, n_list, zones_list, width, block_rows,
+            _agg.AGG_META_COLS)
+        if with_sum:
+            w_off, tabs = 0, []
+            for s_idx, (meta, wts) in enumerate(zip(metas, weights_list)):
+                meta[:, 4] = w_off
+                wts = np.asarray(wts, np.int32).reshape(-1)
+                tabs.append(wts)
+                w_off += wts.shape[0]
+                _tile_weight_sums(meta, packed_list[s_idx], n_list[s_idx],
+                                  zones_list[s_idx], wts, width, block_rows)
+            flat = np.concatenate(tabs) if tabs else np.zeros(0, np.int32)
+            pad = -(-max(1, flat.shape[0]) // LANES) * LANES
+            weights = np.zeros(pad, np.int32)
+            weights[:flat.shape[0]] = flat
+            weights = weights.reshape(-1, LANES)
+        else:
+            weights = np.zeros((1, LANES), np.int32)
+        meta_all = np.concatenate(metas)
+        meta_all[:, 2] = (np.repeat(np.arange(len(seg_tiles)), seg_tiles)
+                          * n_preds)
+        ranges_all = np.concatenate(
+            [np.asarray(r, np.uint32).reshape(-1, 2) for r in ranges_list])
+    cnts, mins, maxs, sums, flags = _launch(
+        st, _agg.fused_zone_agg_2d, [words_all, meta_all, ranges_all, weights],
+        width=width, n_preds=n_preds, with_sum=with_sum,
+        block_rows=block_rows)
+    cnts = cnts.astype(np.int64)
+    mins = mins.astype(np.int64)
+    maxs = maxs.astype(np.int64)
+    sums = sums.astype(np.int64)
+    flags = flags.reshape(-1)
 
     per_sct, t_off = [], 0
     for n_tiles in seg_tiles:
@@ -395,7 +437,7 @@ def fused_level_agg(
 
 def level_histogram(
     packed_list, n_list, edges_list, zones_list, width: int,
-    block_rows: int = _fused.DEFAULT_BLOCK_ROWS,
+    block_rows: int = _fused.DEFAULT_BLOCK_ROWS, stats=None,
 ):
     """ONE launch computing a per-code-bucket histogram over every packed
     column of a level (the GROUP BY gather).
@@ -407,26 +449,28 @@ def level_histogram(
 
     Returns (hists, info): hists[s] is int64 [B_s]; info carries the tile
     telemetry (a short-circuited tile contributed its whole entry count
-    to one bin without reading data).
+    to one bin without reading data).  ``stats`` as in
+    ``fused_level_filter``.
     """
+    st = stats if stats is not None else _Untimed()
     n_bins = max(len(e) - 1 for e in edges_list)
     assert n_bins <= _agg.MAX_BINS, n_bins
-    words_all, metas, _seg_words, seg_tiles = _level_tiles(
-        packed_list, n_list, zones_list, width, block_rows,
-        _agg.AGG_META_COLS)
-    edges = np.zeros((len(edges_list), n_bins + 1), np.uint32)
-    for s_idx, e in enumerate(edges_list):
-        e = np.asarray(e, np.uint32).reshape(-1)
-        edges[s_idx, :e.shape[0]] = e
-        edges[s_idx, e.shape[0]:] = e[-1]
-    meta_all = np.concatenate(metas)
-    meta_all[:, 2] = np.repeat(np.arange(len(seg_tiles)), seg_tiles)
-    hist2, flags = _agg.zone_histogram_2d(
-        jnp.asarray(words_all), jnp.asarray(meta_all), jnp.asarray(edges),
-        width=width, n_bins=n_bins, block_rows=block_rows,
-        interpret=INTERPRET)
-    hist2 = np.asarray(hist2).astype(np.int64)
-    flags = np.asarray(flags).reshape(-1)
+    with st.time("ops.prep"):
+        words_all, metas, _seg_words, seg_tiles = _level_tiles(
+            packed_list, n_list, zones_list, width, block_rows,
+            _agg.AGG_META_COLS)
+        edges = np.zeros((len(edges_list), n_bins + 1), np.uint32)
+        for s_idx, e in enumerate(edges_list):
+            e = np.asarray(e, np.uint32).reshape(-1)
+            edges[s_idx, :e.shape[0]] = e
+            edges[s_idx, e.shape[0]:] = e[-1]
+        meta_all = np.concatenate(metas)
+        meta_all[:, 2] = np.repeat(np.arange(len(seg_tiles)), seg_tiles)
+    hist2, flags = _launch(
+        st, _agg.zone_histogram_2d, [words_all, meta_all, edges],
+        width=width, n_bins=n_bins, block_rows=block_rows)
+    hist2 = hist2.astype(np.int64)
+    flags = flags.reshape(-1)
     hists, t_off = [], 0
     for n_tiles, e in zip(seg_tiles, edges_list):
         hists.append(hist2[t_off:t_off + n_tiles].sum(axis=0)[:len(e) - 1])

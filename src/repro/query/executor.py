@@ -42,8 +42,10 @@ StageStats contract (counters for the bench / roofline telemetry):
 ``agg_tiles_{total,skipped,evaluated,shortcircuit}`` (unit: kernel tile
 on the fused path, (block x spec) on the host fast path),
 ``agg_histograms_gathered``, ``agg_codes_decoded``,
-``agg_fastpath_runs`` / ``agg_fallback_runs``, ``agg_launches``,
-``agg_rows_scanned``.
+``agg_fastpath_runs`` / ``agg_fallback_runs``, ``agg_launches``; on
+kernel launches ``h2d_bytes`` / ``d2h_bytes`` and the ``ops.*`` stages
+(``kernels.ops``); on the general path ``gathered_rows`` and the
+``filter`` sub-stages of ``filter_exec``.
 """
 
 from __future__ import annotations
@@ -52,10 +54,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.filter_exec import (_code_masks_many, _fused_level_masks,
-                                    _global_newest, _memtable_newest,
+from repro.core.filter_exec import (_global_newest, _memtable_newest,
                                     _memtable_visible, _read_blob_values,
-                                    string_mask)
+                                    _run_masks, string_mask)
 from repro.core.memtable import MemTables, as_mems
 from repro.core.opd import Predicate
 from repro.core.sct import SCT, BlobManager
@@ -106,7 +107,6 @@ def evaluate_aggregates(
     with stats.time("read"):
         for s in live_runs:
             store.stats.add_read(s.disk_bytes, 1)
-            stats.counts["agg_rows_scanned"] += s.n
 
     if fast:
         stats.counts["agg_fastpath_runs"] += len(live_runs)
@@ -204,7 +204,7 @@ def _fold_scalar(partials, specs, scalar_q, s, counts, min_codes, max_codes,
 def _kernel_scalars(live_runs, idxs, windows, specs, scalar_q, with_sum,
                     partials, stats, block_rows):
     """Scalar specs through ``fused_level_agg``, one launch per
-    (level, pack-width) group — mirrors ``_fused_level_masks``."""
+    (level, pack-width) group — mirrors ``_fused_level_bitmaps``."""
     from repro.kernels import ops as kops
 
     groups: Dict[Tuple[int, int], List[int]] = {}
@@ -223,7 +223,8 @@ def _kernel_scalars(live_runs, idxs, windows, specs, scalar_q, with_sum,
             [live_runs[i].packed for i in members],
             [live_runs[i].n for i in members],
             ranges_list, [_zones_of(live_runs[i]) for i in members],
-            width, weights_list=weights_list, block_rows=block_rows)
+            width, weights_list=weights_list, block_rows=block_rows,
+            stats=stats)
         stats.counts["agg_launches"] += 1
         for key in ("tiles_total", "tiles_skipped", "tiles_evaluated",
                     "tiles_shortcircuit"):
@@ -345,7 +346,7 @@ def _fastpath_group(live_runs, windows, spec, q, partials, stats,
                 [live_runs[i].n for i in members],
                 [by_run[i][0] for i in members],
                 [_zones_of(live_runs[i]) for i in members],
-                width, block_rows=block_rows)
+                width, block_rows=block_rows, stats=stats)
             stats.counts["agg_launches"] += 1
             for key in ("tiles_total", "tiles_skipped", "tiles_evaluated",
                         "tiles_shortcircuit"):
@@ -409,37 +410,28 @@ def _general_aggregate(live_runs, mems, mem_newest, specs, stats, blob_mgr,
             other_n[q] += keys.shape[0]
 
     with stats.time("filter"):
-        fused_masks = (_fused_level_masks(live_runs, preds, stats)
-                       if backend == "fused" else {})
-        for i, s in enumerate(live_runs):
-            if s.codec == "opd":
-                if backend == "fused":
-                    masks = fused_masks[i]
-                else:
-                    ranges = [s.opd.code_range(p) for p in preds]
-                    masks = _code_masks_many(s, ranges, backend)
-            else:
-                vals = s.values if s.codec == "plain" else decoded[i]
-                base = ~s.tombs
-                masks = [string_mask(vals, p) & base for p in preds]
-            for q in range(K):
-                mask = masks[q]
-                if snap is not None:
-                    mask = mask & (s.seqnos <= snap)
-                idx = np.nonzero(mask)[0]
-                if idx.shape[0] == 0:
-                    continue
-                if s.codec == "opd":
-                    _push(q, s.keys[idx], s.seqnos[idx], i, codes=s.evs[idx])
-                else:
-                    vals = s.values if s.codec == "plain" else decoded[i]
-                    _push(q, s.keys[idx], s.seqnos[idx], -1, vals=vals[idx])
-        mk, ms, mv = _memtable_visible(mems, snap, value_width)
-        if mk.shape[0]:
-            for q, p in enumerate(preds):
-                m = string_mask(mv, p)
-                if m.any():
-                    _push(q, mk[m], ms[m], -1, vals=mv[m])
+        for i, s, masks in _run_masks(live_runs, preds, decoded, stats,
+                                      backend, snap):
+            with stats.time("gather"):
+                for q in range(K):
+                    idx = np.nonzero(masks[q])[0]
+                    if idx.shape[0] == 0:
+                        continue
+                    stats.counts["gathered_rows"] += idx.shape[0]
+                    if s.codec == "opd":
+                        _push(q, s.keys[idx], s.seqnos[idx], i,
+                              codes=s.evs[idx])
+                    else:
+                        vals = s.values if s.codec == "plain" else decoded[i]
+                        _push(q, s.keys[idx], s.seqnos[idx], -1,
+                              vals=vals[idx])
+        with stats.time("memtable"):
+            mk, ms, mv = _memtable_visible(mems, snap, value_width)
+            if mk.shape[0]:
+                for q, p in enumerate(preds):
+                    m = string_mask(mv, p)
+                    if m.any():
+                        _push(q, mk[m], ms[m], -1, vals=mv[m])
 
     partials = []
     for q in range(K):

@@ -40,6 +40,8 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Union
 
+from jax.profiler import StepTraceAnnotation
+
 from repro.core.filter_exec import FilterResult
 from repro.core.lsm import LSMTree, Snapshot
 from repro.core.opd import Predicate
@@ -81,7 +83,6 @@ class ScanServerStats:
     n_served: int = 0
     n_batches: int = 0
     batch_sizes: List[int] = dataclasses.field(default_factory=list)
-    wait_seconds: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def mean_batch(self) -> float:
@@ -145,7 +146,15 @@ class ScanServer:
              ) -> Dict[int, QueryResult]:
         """Fill up to ``max_batch`` slots from the queue and execute them
         as ONE batched filter + ONE batched aggregate, both against a
-        single pinned snapshot."""
+        single pinned snapshot.  The step is a profiler step span
+        (``scan_server.step``, numbered by batch), so the engine's spans
+        of one batch share its step number in a trace."""
+        with StepTraceAnnotation("scan_server.step",
+                                 step_num=self.stats.n_batches):
+            return self._step(snapshot)
+
+    def _step(self, snapshot: Optional[AnySnapshot]
+              ) -> Dict[int, QueryResult]:
         raiser = getattr(self.tree, "raise_maintenance_errors", None)
         if raiser is not None:
             # a read-only server must not silently serve over a dead
@@ -163,7 +172,6 @@ class ScanServer:
             # pin here, not inside the engine calls, so the batch's
             # filters and aggregates observe one consistent version
             snapshot = self.tree.snapshot()
-        now = time.perf_counter()
         # dequeue only after the batch succeeds: a failing engine call
         # leaves the requests queued for a retry instead of losing them
         filter_res = self.tree.filter_many(
@@ -176,7 +184,6 @@ class ScanServer:
             r.result = res
             r.done = True
             out[r.rid] = res
-            self.stats.wait_seconds.append(now - r.submitted_at)
         self.stats.n_batches += 1
         self.stats.n_served += len(slots)
         self.stats.batch_sizes.append(len(slots))

@@ -136,6 +136,8 @@ class ShardedLSM:
         self._retired_stages: Dict[str, StageStats] = {
             name: StageStats() for name in _STAGE_STATS}
         self._retired_counts: Dict[str, int] = {c: 0 for c in _COUNTERS}
+        # the engine's own stage: the gather of the shards' results
+        self.shard_stats = StageStats("shard")
 
     # ------------------------------------------------------------------ #
     # manifests + restart
@@ -474,7 +476,8 @@ class ShardedLSM:
         snap = snapshot or self.snapshot()
         results = self._scan_map(
             lambda e: e[0].filter(pred, snapshot=e[1]), snap.entries(), snap)
-        return self._gather(results)
+        with self.shard_stats.time("gather"):
+            return self._gather(results)
 
     def filter_many(self, preds: List[Predicate],
                     snapshot: Optional[ShardSnapshot] = None
@@ -487,8 +490,9 @@ class ShardedLSM:
         per_shard = self._scan_map(
             lambda e: e[0].filter_many(preds, snapshot=e[1]),
             snap.entries(), snap)
-        return [self._gather([shard_res[q] for shard_res in per_shard])
-                for q in range(len(preds))]
+        with self.shard_stats.time("gather"):
+            return [self._gather([shard_res[q] for shard_res in per_shard])
+                    for q in range(len(preds))]
 
     def aggregate(self, spec, snapshot: Optional[ShardSnapshot] = None):
         """One aggregate, scatter-gathered -> ``AggResult``."""
@@ -510,13 +514,12 @@ class ShardedLSM:
         snap = snapshot or self.snapshot()
         if any(spec.group is not None and not spec.group.resolved()
                for spec in specs):
-            with self.agg_stats.time("plan"):
-                domains = [collect_domain(t_snap.runs, t_snap.mems,
-                                          tree.blob_mgr, self.cfg.value_width)
-                           for tree, t_snap in snap.entries()]
-                domains = [d for d in domains if d.shape[0]]
-                domain = (np.unique(np.concatenate(domains)) if domains
-                          else np.zeros(0, f"S{self.cfg.value_width}"))
+            domains = [collect_domain(t_snap.runs, t_snap.mems,
+                                      tree.blob_mgr, self.cfg.value_width)
+                       for tree, t_snap in snap.entries()]
+            domains = [d for d in domains if d.shape[0]]
+            domain = (np.unique(np.concatenate(domains)) if domains
+                      else np.zeros(0, f"S{self.cfg.value_width}"))
             specs = resolve_specs(specs, domain)
         per_shard = self._scan_map(
             lambda e: e[0].aggregate_partials(specs, snapshot=e[1]),

@@ -89,3 +89,27 @@ def test_remap_pack_compiles(shape, width):
     _compile(merge_remap.remap_pack_codes_3d, codes, codes,
              shape((REMAP_TABLE,), jnp.int32), shape((8,), jnp.int32),
              width=width)
+
+
+@pytest.mark.parametrize("fn,kernel", [
+    (fused_scan.fused_zone_filter_2d, "fused_zone_filter"),
+    (agg_scan.fused_zone_agg_2d, "fused_zone_agg"),
+    (agg_scan.zone_histogram_2d, "zone_histogram")])
+def test_served_kernels_carry_their_names(shape, fn, kernel):
+    """Each served-path ``pallas_call`` is named, inside the program of
+    its jitted wrapper (whose name the rooflines read)."""
+    rows, tiles = 2 * fused_scan.DEFAULT_BLOCK_ROWS, 2
+    words = shape((rows, fused_scan.LANES), jnp.uint32)
+    if fn is fused_scan.fused_zone_filter_2d:
+        args = (shape((tiles, fused_scan.META_COLS), jnp.uint32),
+                shape((2, 2), jnp.uint32))
+    elif fn is agg_scan.fused_zone_agg_2d:
+        args = (shape((tiles, agg_scan.AGG_META_COLS), jnp.uint32),
+                shape((2, 2), jnp.uint32),
+                shape((1, agg_scan.LANES), jnp.int32))
+    else:
+        args = (shape((tiles, agg_scan.AGG_META_COLS), jnp.uint32),
+                shape((1, 9), jnp.uint32))
+    text = fn.lower(words, *args, width=32, interpret=False).as_text()
+    assert f"module @jit_{fn.__name__} " in text
+    assert f'kernel_name = "{kernel}"' in text
