@@ -363,10 +363,12 @@ def _fused_level_bitmaps(
     Each run contributes its own K planned [lo, hi] ranges to the
     group's concatenated range table, so runs with *different
     dictionaries* still share the launch.  Per-block code zones from
-    ``BlockIndex`` gate each tile in-kernel; pruning telemetry lands in
-    ``stats.counts`` (``fused_launches``, ``zone_tiles_*``,
-    ``zone_blocks_*``) for the bench reports, the launch's stages and
-    byte counters in ``stats`` (``kernels.ops.fused_level_filter``).
+    ``BlockIndex`` gate each tile in-kernel, through tile meta built
+    once per run (``query.planner.run_tile_meta``); pruning telemetry
+    lands in ``stats.counts`` (``fused_launches``, ``zone_tiles_*``,
+    ``zone_blocks_*``, ``tile_meta_*``) for the bench reports, the
+    launch's stages and byte counters in ``stats``
+    (``kernels.ops.fused_level_filter``).
 
     Returns {run index -> uint32 [K, n_words] bitmaps, or None when no
     predicate can match anywhere in its level}; ``_expand_bitmaps``
@@ -374,6 +376,8 @@ def _fused_level_bitmaps(
     backends.
     """
     from repro.kernels import ops as kops
+    from repro.kernels.fused_scan import DEFAULT_BLOCK_ROWS
+    from repro.query import planner
 
     groups: dict = {}
     for i, s in enumerate(live_runs):
@@ -381,7 +385,7 @@ def _fused_level_bitmaps(
             groups.setdefault((s.level, s.code_bits), []).append(i)
     out: dict = {}
     for (_level, width), idxs in sorted(groups.items()):
-        ranges_list, zones_list = [], []
+        ranges_list = []
         with stats.time("plan"):
             for i in idxs:
                 s = live_runs[i]
@@ -390,19 +394,18 @@ def _fused_level_bitmaps(
                 ranges_list.append(np.asarray(
                     [(lo, hi - 1) if lo < hi else (1, 0) for lo, hi in rr],
                     np.uint32))
-                b = s.blocks
-                zones_list.append(
-                    (b.code_lo, b.code_hi, b.entries_per_block)
-                    if b is not None and b.has_zones else None)
         if all((r[:, 0] > r[:, 1]).all() for r in ranges_list):
             # no predicate can match anywhere in this level: skip the
             # launch entirely (keeps fused_launches honest)
             out.update((i, None) for i in idxs)
             continue
+        runs = [live_runs[i] for i in idxs]
         bitmaps, info = kops.fused_level_filter(
-            [live_runs[i].packed for i in idxs],
-            [live_runs[i].n for i in idxs],
-            ranges_list, zones_list, width, stats=stats)
+            [s.packed for s in runs], [s.n for s in runs], ranges_list,
+            [planner.run_zones(s) for s in runs], width,
+            block_rows=DEFAULT_BLOCK_ROWS, stats=stats,
+            metas_list=[planner.run_tile_meta(s, DEFAULT_BLOCK_ROWS, stats)
+                        for s in runs])
         stats.counts["fused_launches"] += 1
         for k in ("tiles_total", "tiles_skipped", "blocks_total",
                   "blocks_skipped", "blocks_prunable"):

@@ -142,11 +142,118 @@ def _launch(st, kernel, inputs, **static) -> list:
 
 
 # --------------------------------------------------------------------------- #
+# level operands: per-SCT tile meta and the padded word column
+# --------------------------------------------------------------------------- #
+def tile_meta(packed, n: int, zones, width: int, block_rows: int,
+              wtab=None) -> np.ndarray:
+    """The query-independent meta rows of one SCT's tiles (``block_rows``
+    x 128 packed words each), uint32 ``[n_tiles, AGG_META_COLS]`` in the
+    agg kernels' layout:
+
+      col 0, 1  zone: min / max of the 4 KB block zones the tile covers;
+                ``EMPTY_ZONE`` on a padding-only tile; ``(0, 0xFFFFFFFF)``
+                when ``zones`` is None (forced evaluation; z_lo = 0 also
+                blocks the closed form, so tombstones stay safe)
+      col 3     n_valid: the real entries inside the tile
+      col 5     the tile's EXACT weight total, or ``WSUM_SENTINEL`` (no
+                SUM closed form) when ``wtab`` or the block weight sums
+                ``zones[3]`` are absent, or the total is outside
+                [0, 2**31), the kernel's int32 accumulator
+      col 2, 4  range base and weight base: 0 here, set per launch
+
+    ``zones`` is (code_lo, code_hi, entries_per_block[, weight_sums]).
+    Tiles and blocks do not align: a ``reduceat`` over each tile's first
+    block, folded with the block the tile shares with the next one, gives
+    the zones; the weight totals are cumulative block sums plus one
+    gather of the edge-block entries before every tile boundary.  The
+    edge entries read tombstones as code 0 and charge ``wtab[0]``, which
+    the (tombstone-zeroed) block sums do not; that only touches blocks
+    whose zone starts at 0, which force ``z_lo = 0`` on every tile
+    covering them, so the kernel never uses those tiles' totals.
+
+    The result depends only on the SCT and ``(width, block_rows)``; SCTs
+    are immutable, so the engine builds it once per SCT
+    (``query.planner.run_tile_meta``).
+    """
+    per = 32 // width
+    tile_entries = block_rows * LANES * per
+    words = np.asarray(packed, np.uint32).reshape(-1)
+    n_tiles = max(1, -(-words.shape[0] // (block_rows * LANES)))
+    # entry bounds: tile t holds entries [bounds[t], bounds[t + 1])
+    bounds = np.minimum(int(n), np.arange(n_tiles + 1, dtype=np.int64)
+                        * tile_entries)
+    n_valid = np.diff(bounds)
+    n_live = int(np.count_nonzero(n_valid))  # live tiles are a prefix
+    meta = np.zeros((n_tiles, _agg.AGG_META_COLS), np.uint32)
+    meta[:, 3] = n_valid
+    meta[n_live:, 0], meta[n_live:, 1] = _agg.EMPTY_ZONE
+    meta[:, _agg.WSUM_COL] = _agg.WSUM_SENTINEL
+    if zones is None:
+        meta[:n_live, 1] = 0xFFFFFFFF
+        return meta
+    code_lo, code_hi, epb = zones[0], zones[1], int(zones[2])
+    if n_live:
+        b0 = bounds[:n_live] // epb
+        b1 = (bounds[1:n_live + 1] - 1) // epb
+        last = int(b1[-1]) + 1
+        meta[:n_live, 0] = np.minimum(
+            np.minimum.reduceat(code_lo[:last], b0), code_lo[b1])
+        meta[:n_live, 1] = np.maximum(
+            np.maximum.reduceat(code_hi[:last], b0), code_hi[b1])
+    ws = zones[3] if len(zones) > 3 else None
+    wtab = None if wtab is None else np.asarray(wtab, np.int64).reshape(-1)
+    if ws is None or wtab is None or wtab.shape[0] == 0:
+        return meta
+    # weight total of entries [0, e): whole blocks, then entries
+    # [b * epb, e) of the block b = e // epb that e falls in
+    blk = bounds // epb
+    k = bounds - blk * epb
+    off = np.cumsum(k) - k
+    idx = np.repeat(blk * epb - off, k) + np.arange(int(k.sum()))
+    fields = (words[idx // per] >> (idx % per * width).astype(np.uint32)) \
+        & np.uint32((1 << width) - 1)
+    cw = np.concatenate([[0], np.cumsum(wtab[fields])])
+    cum = np.concatenate([[0], np.cumsum(np.asarray(ws, np.int64))])
+    tot = np.diff(cum[blk] + cw[off + k] - cw[off])
+    fits = (tot >= 0) & (tot < 2**31)
+    meta[fits, _agg.WSUM_COL] = tot[fits]
+    return meta
+
+
+def _level_tiles(packed_list, n_list, zones_list, width: int,
+                 block_rows: int, metas_list=None, weights_list=None):
+    """The operands every level-wide launch shares.  Each SCT's packed
+    words are padded to whole tiles with 0xFFFFFFFF and concatenated
+    ([rows, 128]); the SCTs' tile meta (``metas_list``, the callers'
+    cached ``tile_meta``, or built here with ``weights_list`` as the
+    weight tables) is concatenated into a fresh array whose column 2
+    holds each tile's SCT index.
+
+    Returns (words_all, meta_all, seg_words, seg_tiles)."""
+    tile_words = block_rows * LANES
+    words = [np.asarray(p, np.uint32).reshape(-1) for p in packed_list]
+    seg_words = [w.shape[0] for w in words]
+    seg_tiles = [max(1, -(-m // tile_words)) for m in seg_words]
+    words_all = np.full(sum(seg_tiles) * tile_words, 0xFFFFFFFF, np.uint32)
+    off = 0
+    for w, n_tiles in zip(words, seg_tiles):
+        words_all[off:off + w.shape[0]] = w
+        off += n_tiles * tile_words
+    if metas_list is None:
+        wtabs = weights_list or [None] * len(words)
+        metas_list = [tile_meta(w, n, z, width, block_rows, wt)
+                      for w, n, z, wt in zip(words, n_list, zones_list, wtabs)]
+    meta_all = np.concatenate(metas_list)
+    meta_all[:, 2] = np.repeat(np.arange(len(seg_tiles)), seg_tiles)
+    return words_all.reshape(-1, LANES), meta_all, seg_words, seg_tiles
+
+
+# --------------------------------------------------------------------------- #
 # fused_scan: one zone-gated launch over every SCT of a level
 # --------------------------------------------------------------------------- #
 def fused_level_filter(
     packed_list, n_list, ranges_list, zones_list, width: int,
-    block_rows: int = _fused.DEFAULT_BLOCK_ROWS, stats=None,
+    block_rows: int = _fused.DEFAULT_BLOCK_ROWS, stats=None, metas_list=None,
 ):
     """ONE kernel launch evaluating K code ranges over S packed columns.
 
@@ -162,8 +269,11 @@ def fused_level_filter(
       packed_list: per-SCT uint32 packed words (s.packed)
       n_list:      per-SCT entry counts
       ranges_list: per-SCT uint32 [K, 2] inclusive [lo, hi]; lo > hi empty
-      zones_list:  per-SCT (code_lo, code_hi, entries_per_block) or None
-                   (no zones -> tiles marked always-hit, never pruned)
+      zones_list:  per-SCT (code_lo, code_hi, entries_per_block[, ...])
+                   or None (no zones -> tiles marked always-hit, never
+                   pruned)
+      metas_list:  per-SCT ``tile_meta`` for this ``(width, block_rows)``,
+                   as the engine caches it; None builds it here
 
     Returns (bitmaps, info): bitmaps[s] is uint32 [K, n_words_s] aligned
     with packed_list[s] (bit-identical to ``multi_range_filter_packed``
@@ -177,36 +287,18 @@ def fused_level_filter(
     tile_entries = tile_words * per
     n_preds = int(np.asarray(ranges_list[0], np.uint32).reshape(-1, 2).shape[0])
     with st.time("ops.prep"):
-        chunks, metas, seg_words, seg_tiles = [], [], [], []
-        for s_idx, (packed, n, zones) in enumerate(
-                zip(packed_list, n_list, zones_list)):
-            words = np.asarray(packed, np.uint32).reshape(-1)
-            m = words.shape[0]
-            n_tiles = max(1, -(-m // tile_words))
-            pad = np.full(n_tiles * tile_words, 0xFFFFFFFF, np.uint32)
-            pad[:m] = words
-            chunks.append(pad)
-            seg_words.append(m)
-            seg_tiles.append(n_tiles)
-            meta = np.zeros((n_tiles, _fused.META_COLS), np.uint32)
-            meta[:, 2] = s_idx * n_preds
+        words_all, tiles, seg_words, seg_tiles = _level_tiles(
+            packed_list, n_list, zones_list, width, block_rows, metas_list)
+        # (zone_lo, zone_hi, range_base, reserved)
+        meta_all = np.zeros((tiles.shape[0], _fused.META_COLS), np.uint32)
+        meta_all[:, :3] = tiles[:, :3]
+        meta_all[:, 2] *= n_preds
+        t_off = 0
+        for zones, m, n_tiles in zip(zones_list, seg_words, seg_tiles):
             if zones is None or m == 0:
-                # no zone map: every tile is a forced hit (full evaluation)
-                meta[:, 0], meta[:, 1] = 0, 0xFFFFFFFF
-            else:
-                code_lo, code_hi, epb = zones
-                for t in range(n_tiles):
-                    e0 = t * tile_entries
-                    e1 = min(int(n), (t + 1) * tile_entries)
-                    if e0 >= e1:  # padding-only tile: always skipped
-                        meta[t, 0], meta[t, 1] = _fused.EMPTY_ZONE
-                        continue
-                    b0, b1 = e0 // epb, (e1 - 1) // epb
-                    meta[t, 0] = code_lo[b0:b1 + 1].min()
-                    meta[t, 1] = code_hi[b0:b1 + 1].max()
-            metas.append(meta)
-        words_all = np.concatenate(chunks).reshape(-1, LANES)
-        meta_all = np.concatenate(metas)
+                # no zone map: every tile, padding too, is a forced hit
+                meta_all[t_off:t_off + n_tiles, :2] = (0, 0xFFFFFFFF)
+            t_off += n_tiles
         ranges_all = np.concatenate(
             [np.asarray(r, np.uint32).reshape(-1, 2) for r in ranges_list])
     bitmaps2, hits2 = _launch(
@@ -225,7 +317,7 @@ def fused_level_filter(
         bitmaps.append(flat[:, w_off:w_off + m])
         zones = zones_list[s_idx]
         if zones is not None:
-            code_lo, code_hi, epb = zones
+            code_lo, code_hi, epb = zones[0], zones[1], zones[2]
             nb = int(code_lo.shape[0])
             info["blocks_total"] += nb
             # a block is skipped iff EVERY tile overlapping it was
@@ -259,97 +351,6 @@ def bitmap_to_mask(bitmap: np.ndarray, width: int, n: int) -> np.ndarray:
 # --------------------------------------------------------------------------- #
 # agg_scan: zone-gated aggregation directly on packed codes
 # --------------------------------------------------------------------------- #
-def _level_tiles(packed_list, n_list, zones_list, width: int,
-                 block_rows: int, meta_cols: int):
-    """Shared tile/meta builder for the level-wide agg launches: pads each
-    SCT's packed words to tile boundaries with 0xFFFFFFFF, concatenates,
-    and fills the per-tile meta rows (zone aggregated from the 4 KB block
-    zones the tile covers, n_valid = real entries inside the tile)."""
-    per = 32 // width
-    tile_words = block_rows * LANES
-    tile_entries = tile_words * per
-    chunks, metas, seg_words, seg_tiles = [], [], [], []
-    for s_idx, (packed, n, zones) in enumerate(
-            zip(packed_list, n_list, zones_list)):
-        words = np.asarray(packed, np.uint32).reshape(-1)
-        m = words.shape[0]
-        n_tiles = max(1, -(-m // tile_words))
-        pad = np.full(n_tiles * tile_words, 0xFFFFFFFF, np.uint32)
-        pad[:m] = words
-        chunks.append(pad)
-        seg_words.append(m)
-        seg_tiles.append(n_tiles)
-        meta = np.zeros((n_tiles, meta_cols), np.uint32)
-        if meta_cols > _agg.WSUM_COL:
-            # no weight sum known (yet): sentinel blocks the SUM closed
-            # form; fused_level_agg overwrites with exact per-tile sums
-            meta[:, _agg.WSUM_COL] = _agg.WSUM_SENTINEL
-        for t in range(n_tiles):
-            e0 = t * tile_entries
-            e1 = min(int(n), (t + 1) * tile_entries)
-            meta[t, 3] = max(0, e1 - e0)
-            if e0 >= e1:  # padding-only tile: always skipped
-                meta[t, 0], meta[t, 1] = _agg.EMPTY_ZONE
-            elif zones is None:
-                # no zone map: forced evaluation (z_lo = 0 also blocks
-                # the closed-form path, so tombstones stay safe)
-                meta[t, 0], meta[t, 1] = 0, 0xFFFFFFFF
-            else:
-                code_lo, code_hi, epb = zones[0], zones[1], zones[2]
-                b0, b1 = e0 // epb, (e1 - 1) // epb
-                meta[t, 0] = code_lo[b0:b1 + 1].min()
-                meta[t, 1] = code_hi[b0:b1 + 1].max()
-        metas.append(meta)
-    words_all = np.concatenate(chunks).reshape(-1, LANES)
-    return words_all, metas, seg_words, seg_tiles
-
-
-def _tile_weight_sums(meta, packed, n, zones, wtab, width: int,
-                      block_rows: int) -> None:
-    """Fill ``meta[:, WSUM_COL]`` with the EXACT weight total of each
-    tile's entries: cumulative 4 KB-block sums plus edge-block
-    corrections gathered from the packed words (tile boundaries rarely
-    align with block boundaries).  Tiles keep the sentinel — blocking
-    the SUM closed form — when the SCT carries no block weight sums, or
-    when a total would not fit the kernel's int32 accumulator.
-
-    Edge-block corrections read tombstones as code 0 and charge
-    ``wtab[0]``; that is only inconsistent with the (tombstone-zeroed)
-    block sums for blocks whose zone starts at 0 — exactly the blocks
-    that force ``z_lo = 0`` on every tile covering them, so the kernel
-    never uses those tiles' totals."""
-    ws = zones[3] if zones is not None and len(zones) > 3 else None
-    wtab = np.asarray(wtab, np.int64).reshape(-1)
-    if ws is None or wtab.shape[0] == 0:
-        return
-    per = 32 // width
-    tile_entries = block_rows * LANES * per
-    epb = zones[2]
-    words = np.asarray(packed, np.uint32).reshape(-1)
-    cum = np.concatenate([[0], np.cumsum(np.asarray(ws, np.int64))])
-    fmask = np.uint32((1 << width) - 1)
-
-    def prefix(e: int) -> int:  # weight total of entries [0, e)
-        b = e // epb
-        a = b * epb
-        part = 0
-        if a < e:
-            w0 = a // per
-            seg = words[w0: (e - 1) // per + 1]
-            fields = np.zeros(seg.shape[0] * per, np.int64)
-            for f in range(per):
-                fields[f::per] = (seg >> np.uint32(f * width)) & fmask
-            part = int(wtab[fields[a - w0 * per: e - w0 * per]].sum())
-        return int(cum[b]) + part
-
-    pref = [prefix(min(int(n), t * tile_entries))
-            for t in range(meta.shape[0] + 1)]
-    for t in range(meta.shape[0]):
-        v = pref[t + 1] - pref[t]
-        if 0 <= v < 2**31:
-            meta[t, _agg.WSUM_COL] = np.uint32(v)
-
-
 def _tile_info(flags: np.ndarray) -> dict:
     return {
         "tiles_total": int(flags.shape[0]),
@@ -362,7 +363,7 @@ def _tile_info(flags: np.ndarray) -> dict:
 def fused_level_agg(
     packed_list, n_list, ranges_list, zones_list, width: int,
     weights_list=None, block_rows: int = _fused.DEFAULT_BLOCK_ROWS,
-    stats=None,
+    stats=None, metas_list=None,
 ):
     """ONE launch computing K (count, min, max[, sum]) partials over every
     packed column of a level, folded per SCT on the host.
@@ -370,9 +371,12 @@ def fused_level_agg(
       packed_list:  per-SCT uint32 packed words (s.packed)
       n_list:       per-SCT entry counts
       ranges_list:  per-SCT uint32 [K, 2] inclusive [lo, hi]; lo > hi empty
-      zones_list:   per-SCT (code_lo, code_hi, entries_per_block) or None
+      zones_list:   per-SCT (code_lo, code_hi, entries_per_block[,
+                    weight_sums]) or None
       weights_list: per-SCT int32 numeric weight per code (enables SUM;
                     ranges must then lie inside each dictionary)
+      metas_list:   as in ``fused_level_filter``, its weight totals built
+                    from the same weights
 
     Returns (per_sct, info): per_sct[s] is a dict with int64 arrays
     ``counts``/``sums`` [K] and ``min_code``/``max_code`` [K] (-1 when no
@@ -385,28 +389,23 @@ def fused_level_agg(
     n_preds = int(np.asarray(ranges_list[0], np.uint32).reshape(-1, 2).shape[0])
     with_sum = weights_list is not None
     with st.time("ops.prep"):
-        words_all, metas, _seg_words, seg_tiles = _level_tiles(
-            packed_list, n_list, zones_list, width, block_rows,
-            _agg.AGG_META_COLS)
+        words_all, meta_all, _seg_words, seg_tiles = _level_tiles(
+            packed_list, n_list, zones_list, width, block_rows, metas_list,
+            weights_list)
+        meta_all[:, 2] *= n_preds
         if with_sum:
-            w_off, tabs = 0, []
-            for s_idx, (meta, wts) in enumerate(zip(metas, weights_list)):
-                meta[:, 4] = w_off
-                wts = np.asarray(wts, np.int32).reshape(-1)
-                tabs.append(wts)
-                w_off += wts.shape[0]
-                _tile_weight_sums(meta, packed_list[s_idx], n_list[s_idx],
-                                  zones_list[s_idx], wts, width, block_rows)
+            tabs = [np.asarray(w, np.int32).reshape(-1) for w in weights_list]
+            sizes = [t.shape[0] for t in tabs]
+            meta_all[:, 4] = np.repeat(np.cumsum([0] + sizes[:-1]), seg_tiles)
             flat = np.concatenate(tabs) if tabs else np.zeros(0, np.int32)
             pad = -(-max(1, flat.shape[0]) // LANES) * LANES
             weights = np.zeros(pad, np.int32)
             weights[:flat.shape[0]] = flat
             weights = weights.reshape(-1, LANES)
         else:
+            # no weight table: no SUM closed form
+            meta_all[:, _agg.WSUM_COL] = _agg.WSUM_SENTINEL
             weights = np.zeros((1, LANES), np.int32)
-        meta_all = np.concatenate(metas)
-        meta_all[:, 2] = (np.repeat(np.arange(len(seg_tiles)), seg_tiles)
-                          * n_preds)
         ranges_all = np.concatenate(
             [np.asarray(r, np.uint32).reshape(-1, 2) for r in ranges_list])
     cnts, mins, maxs, sums, flags = _launch(
@@ -437,7 +436,7 @@ def fused_level_agg(
 
 def level_histogram(
     packed_list, n_list, edges_list, zones_list, width: int,
-    block_rows: int = _fused.DEFAULT_BLOCK_ROWS, stats=None,
+    block_rows: int = _fused.DEFAULT_BLOCK_ROWS, stats=None, metas_list=None,
 ):
     """ONE launch computing a per-code-bucket histogram over every packed
     column of a level (the GROUP BY gather).
@@ -449,23 +448,21 @@ def level_histogram(
 
     Returns (hists, info): hists[s] is int64 [B_s]; info carries the tile
     telemetry (a short-circuited tile contributed its whole entry count
-    to one bin without reading data).  ``stats`` as in
-    ``fused_level_filter``.
+    to one bin without reading data).  ``stats`` and ``metas_list`` as
+    in ``fused_level_filter``.
     """
     st = stats if stats is not None else _Untimed()
     n_bins = max(len(e) - 1 for e in edges_list)
     assert n_bins <= _agg.MAX_BINS, n_bins
     with st.time("ops.prep"):
-        words_all, metas, _seg_words, seg_tiles = _level_tiles(
-            packed_list, n_list, zones_list, width, block_rows,
-            _agg.AGG_META_COLS)
+        words_all, meta_all, _seg_words, seg_tiles = _level_tiles(
+            packed_list, n_list, zones_list, width, block_rows, metas_list)
+        meta_all[:, _agg.WSUM_COL] = _agg.WSUM_SENTINEL
         edges = np.zeros((len(edges_list), n_bins + 1), np.uint32)
         for s_idx, e in enumerate(edges_list):
             e = np.asarray(e, np.uint32).reshape(-1)
             edges[s_idx, :e.shape[0]] = e
             edges[s_idx, e.shape[0]:] = e[-1]
-        meta_all = np.concatenate(metas)
-        meta_all[:, 2] = np.repeat(np.arange(len(seg_tiles)), seg_tiles)
     hist2, flags = _launch(
         st, _agg.zone_histogram_2d, [words_all, meta_all, edges],
         width=width, n_bins=n_bins, block_rows=block_rows)

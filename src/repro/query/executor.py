@@ -162,18 +162,6 @@ def _fastpath_aggregate(live_runs, specs, stats, backend, block_rows):
     return partials
 
 
-def _zones_of(s: SCT):
-    """(code_lo, code_hi, entries_per_block, weight_sums) — the last
-    entry is the per-block SUM weight total (None on SCTs built before
-    it existed); tile builders index positionally so 3-tuples from older
-    callers/tests keep working."""
-    b = s.blocks
-    if b is None or not b.has_zones:
-        return None
-    return (b.code_lo, b.code_hi, b.entries_per_block,
-            getattr(b, "weight_sums", None))
-
-
 def _decode_one(s: SCT, code: int, stats) -> bytes:
     stats.counts["agg_codes_decoded"] += 1
     return bytes(s.opd.values[int(code)])
@@ -217,14 +205,15 @@ def _kernel_scalars(live_runs, idxs, windows, specs, scalar_q, with_sum,
                         for q in scalar_q
                         for lo, hi in [windows[i][q]]], np.uint32)
             for i in members]
-        weights_list = ([planner.run_weights(live_runs[i]) for i in members]
+        runs = [live_runs[i] for i in members]
+        weights_list = ([planner.run_weights(s) for s in runs]
                         if with_sum else None)
         per_sct, info = kops.fused_level_agg(
-            [live_runs[i].packed for i in members],
-            [live_runs[i].n for i in members],
-            ranges_list, [_zones_of(live_runs[i]) for i in members],
-            width, weights_list=weights_list, block_rows=block_rows,
-            stats=stats)
+            [s.packed for s in runs], [s.n for s in runs], ranges_list,
+            [planner.run_zones(s) for s in runs], width,
+            weights_list=weights_list, block_rows=block_rows, stats=stats,
+            metas_list=[planner.run_tile_meta(s, block_rows, stats)
+                        for s in runs])
         stats.counts["agg_launches"] += 1
         for key in ("tiles_total", "tiles_skipped", "tiles_evaluated",
                     "tiles_shortcircuit"):
@@ -246,7 +235,7 @@ def _host_scalars(s, windows, specs, scalar_q, partials, stats):
     sums = np.zeros(K, np.int64)
     min_codes = np.full(K, -1, np.int64)
     max_codes = np.full(K, -1, np.int64)
-    zones = _zones_of(s)
+    zones = planner.run_zones(s)
     evs = None
     for k, q in enumerate(scalar_q):
         lo, hi = windows[q]
@@ -341,12 +330,14 @@ def _fastpath_group(live_runs, windows, spec, q, partials, stats,
             s = live_runs[i]
             groups.setdefault((s.level, s.code_bits), []).append(i)
         for (_level, width), members in sorted(groups.items()):
+            runs = [live_runs[i] for i in members]
             hists, info = kops.level_histogram(
-                [live_runs[i].packed for i in members],
-                [live_runs[i].n for i in members],
+                [s.packed for s in runs], [s.n for s in runs],
                 [by_run[i][0] for i in members],
-                [_zones_of(live_runs[i]) for i in members],
-                width, block_rows=block_rows, stats=stats)
+                [planner.run_zones(s) for s in runs], width,
+                block_rows=block_rows, stats=stats,
+                metas_list=[planner.run_tile_meta(s, block_rows, stats)
+                            for s in runs])
             stats.counts["agg_launches"] += 1
             for key in ("tiles_total", "tiles_skipped", "tiles_evaluated",
                         "tiles_shortcircuit"):

@@ -67,6 +67,41 @@ def run_weights(s: SCT) -> np.ndarray:
     return v
 
 
+def run_zones(s: SCT):
+    """(code_lo, code_hi, entries_per_block, weight_sums) of the run's
+    block index, or None without a zone map; the last entry is the
+    per-block SUM weight total (None on SCTs built before it existed).
+    Tile builders index positionally, so 3-tuples keep working."""
+    b = s.blocks
+    if b is None or not b.has_zones:
+        return None
+    return (b.code_lo, b.code_hi, b.entries_per_block,
+            getattr(b, "weight_sums", None))
+
+
+def run_tile_meta(s: SCT, block_rows: int, stats) -> np.ndarray:
+    """The run's per-tile kernel meta (``kernels.ops.tile_meta``: zones,
+    n_valid, SUM weight totals), built once per (pack width,
+    block_rows).  Counts ``tile_meta_builds`` / ``tile_meta_hits`` in
+    ``stats.counts``."""
+    cache = getattr(s, "_q_tile_meta", None)
+    if cache is None:
+        cache = {}
+        s._q_tile_meta = cache
+    key = (s.code_bits, block_rows)
+    meta = cache.get(key)
+    if meta is None:  # two threads that miss at once build the same meta
+        from repro.kernels import ops as kops
+
+        meta = kops.tile_meta(s.packed, s.n, run_zones(s), s.code_bits,
+                              block_rows, run_weights(s))
+        cache[key] = meta
+        stats.counts["tile_meta_builds"] += 1
+    else:
+        stats.counts["tile_meta_hits"] += 1
+    return meta
+
+
 def run_prefix_table(s: SCT, prefix_len: int) -> np.ndarray:
     """S<prefix_len> label per dictionary code (group labels are one
     gather away from a code histogram)."""

@@ -148,17 +148,13 @@ def test_agg_kernel_matches_ref(width, with_sum):
     ranges = np.asarray([(1, maxv - 1), (1, 0),
                          (maxv // 4, maxv // 2)], np.uint32)
     n_preds = ranges.shape[0]
-    words_all, metas, _w, seg_tiles = ops._level_tiles(
-        packed_list, n_list, zones_list, width, block_rows,
-        agg_scan.AGG_META_COLS)
-    meta = np.concatenate(metas)
-    meta[:, 2] = np.repeat(np.arange(len(seg_tiles)), seg_tiles) * n_preds
+    words_all, meta, _w, seg_tiles = ops._level_tiles(
+        packed_list, n_list, zones_list, width, block_rows)
+    meta[:, 2] *= n_preds
     if with_sum:
-        w_off, tabs = 0, []
-        for s, m in enumerate(metas):
-            m[:, 4] = w_off
-            tabs.append(rng.integers(0, 1000, maxv).astype(np.int32))
-            w_off += maxv
+        meta[:, 4] = np.repeat(np.arange(len(seg_tiles)) * maxv, seg_tiles)
+        tabs = [rng.integers(0, 1000, maxv).astype(np.int32)
+                for _ in seg_tiles]
         flat = np.concatenate(tabs)
         pad = -(-flat.shape[0] // agg_scan.LANES) * agg_scan.LANES
         weights = np.zeros(pad, np.int32)
@@ -189,11 +185,8 @@ def test_hist_kernel_matches_ref(width):
     n_bins = 5
     edges_row = np.sort(rng.choice(maxv, n_bins - 1, replace=False))
     edges_row = np.concatenate([[0], edges_row, [maxv]]).astype(np.uint32)
-    words_all, metas, _w, seg_tiles = ops._level_tiles(
-        packed_list, n_list, zones_list, width, block_rows,
-        agg_scan.AGG_META_COLS)
-    meta = np.concatenate(metas)
-    meta[:, 2] = np.repeat(np.arange(len(seg_tiles)), seg_tiles)
+    words_all, meta, _w, seg_tiles = ops._level_tiles(
+        packed_list, n_list, zones_list, width, block_rows)
     edges = np.stack([edges_row] * len(seg_tiles))
     got_h, got_f = agg_scan.zone_histogram_2d(
         jnp.asarray(words_all), jnp.asarray(meta), jnp.asarray(edges),
@@ -223,10 +216,8 @@ def test_agg_kernels_match_ref_many_tiles(kernel):
     edges = np.arange(0, n, epb)
     u = codes.astype(np.uint32)
     zones = (np.minimum.reduceat(u, edges), np.maximum.reduceat(u, edges), epb)
-    words_all, metas, _w, _t = ops._level_tiles(
-        [bitpack(codes, width)], [n], [zones], width, block_rows,
-        agg_scan.AGG_META_COLS)
-    meta = metas[0]
+    words_all, meta, _w, _t = ops._level_tiles(
+        [bitpack(codes, width)], [n], [zones], width, block_rows)
     assert meta.shape[0] == MANY_TILES
     if kernel == "hist":
         edges_tab = np.asarray([[1, 100, 900, 2000, 4000, maxv]], np.uint32)
