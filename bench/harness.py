@@ -15,6 +15,17 @@ against one pinned snapshot), and a request's latency runs from the
 time it was due to the end of the step that answered it.  Requests
 still queued when the window closes are served after it, up to a
 minute, and count with their wait.
+
+Writes (a mix's ``writes``) are served in the same loop: every batch
+that has fallen due goes through ``put_batch`` before the next step, so
+each step's pinned snapshot holds exactly the batches applied before
+it.  A batch's delay runs from its due time to the return of
+``put_batch``, its acknowledgement.  Writes are interleaved between
+steps, not concurrent with them: a batch due during a step waits for
+it.  After warm-up, which writes nothing, set-up fills the memtables to
+the mix's ``prefill_share`` of their flush size (untimed, through
+``put_batch``); the steady phase before the window then applies a write
+stream of its own.
 """
 
 from __future__ import annotations
@@ -28,7 +39,7 @@ import shutil
 import tempfile
 import time
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
@@ -88,6 +99,17 @@ def build_store(cfg: dict, data, spill_dir: Optional[str]):
     return eng
 
 
+def prefill_rows(cfg: dict, mix: dict) -> int:
+    """Updates that fill each shard's memtable to the mix's
+    ``prefill_share`` of its flush size: the store's write buffer over
+    the bytes a version takes in the memtable's count (key, seqno,
+    value), times the shards."""
+    share = float(mix.get("writes", {}).get("prefill_share", 0.0))
+    lcfg = LSMConfig(value_width=int(cfg["value_width"]), **cfg["store"])
+    per_row = lcfg.key_bytes + 8 + lcfg.value_width
+    return int(round(share * lcfg.mem_bytes / per_row)) * int(cfg["shards"])
+
+
 def layout(eng) -> List[List[dict]]:
     """Per shard, the runs a scan launches over: level, entries,
     dictionary size, pack width and 1,024-word tiles."""
@@ -142,6 +164,7 @@ class Batch:
     agg_s: float = 0.0
     filter_launches: int = 0
     agg_launches: int = 0
+    agg_filter_launches: int = 0    # aggregates' general path (memtable rows)
 
 
 class TimedEngine:
@@ -174,11 +197,49 @@ class TimedEngine:
         self.agg_s += time.perf_counter() - t0
         return out
 
+    def put_batch(self, keys, values):
+        with self._annotate("engine.put_batch"):
+            self.eng.put_batch(keys, values)
+
+
+class Writer:
+    """A write stream applied through ``put_batch`` as its batches fall
+    due: item i of a batch is the i-th smallest loaded key, its value
+    ``vocab[id]``.  ``applied`` counts the batches acknowledged."""
+
+    def __init__(self, timed, batches: Sequence[traffic_mod.WriteBatch],
+                 sorted_keys: np.ndarray, vocab: np.ndarray):
+        self.timed = timed
+        self.batches = list(batches)
+        self.sorted_keys = sorted_keys
+        self.vocab = vocab
+        self.applied = 0
+        self.put_s: List[float] = []
+        self.delay_s: List[float] = []
+
+    def next_due(self) -> float:
+        """Seconds after window start of the next batch (inf: none)."""
+        if self.applied < len(self.batches):
+            return self.batches[self.applied].due
+        return float("inf")
+
+    def apply_due(self, t0: float) -> None:
+        while t0 + self.next_due() <= time.perf_counter():
+            b = self.batches[self.applied]
+            keys, values = self.sorted_keys[b.items], self.vocab[b.ids]
+            t = time.perf_counter()
+            self.timed.put_batch(keys, values)
+            t1 = time.perf_counter()
+            self.put_s.append(t1 - t)
+            self.delay_s.append(t1 - (t0 + b.due))
+            self.applied += 1
+
 
 def _step(server, timed, eng, annotate) -> tuple:
     """One ``ScanServer.step`` with its batch record."""
     fl0 = eng.filter_stats.counts["fused_launches"]
     al0 = eng.agg_stats.counts["agg_launches"]
+    afl0 = eng.agg_stats.counts["fused_launches"]
     f0, a0 = timed.filter_s, timed.agg_s
     slots = server.queue[:server.max_batch]
     n_f = sum(1 for r in slots if hasattr(r, "pred"))
@@ -191,7 +252,8 @@ def _step(server, timed, eng, annotate) -> tuple:
               timed.filter_s - f0,
               timed.agg_s - a0,
               eng.filter_stats.counts["fused_launches"] - fl0,
-              eng.agg_stats.counts["agg_launches"] - al0)
+              eng.agg_stats.counts["agg_launches"] - al0,
+              eng.agg_stats.counts["fused_launches"] - afl0)
     return out, b
 
 
@@ -253,13 +315,17 @@ class GcClock:
 
 
 def serve_window(server, timed, eng, requests, seconds: float, keep: set,
-                 trace_dir: Optional[str], clock: CompileClock):
-    """Serve every request due in [0, seconds) open loop.  Returns the
-    per-request latency (s, or None if never answered), the kept
-    answers, the in-window batch records and the traced window."""
+                 trace_dir: Optional[str], clock: CompileClock,
+                 writer: Writer):
+    """Serve every request due in [0, seconds) open loop, and apply the
+    writer's batches as they fall due.  Returns the per-request latency
+    (s, or None if never answered), the kept answers, the number of
+    write batches applied before the step that answered each kept
+    request, the in-window batch records and the traced window."""
     annotate = jax.profiler.TraceAnnotation
     lat: List[Optional[float]] = [None] * len(requests)
     kept: Dict[int, object] = {}
+    kept_writes: Dict[int, int] = {}
     batches: List[Batch] = []
     rid_of: Dict[int, int] = {}
     i = 0
@@ -280,6 +346,7 @@ def serve_window(server, timed, eng, requests, seconds: float, keep: set,
         while i < len(requests) and t0 + requests[i].due <= now:
             rid_of[submit(server, requests[i])] = i
             i += 1
+        writer.apply_due(t0)
         if now >= end and traced is None:
             window_span.__exit__(None, None, None)
             traced = (t0, now)
@@ -295,8 +362,11 @@ def serve_window(server, timed, eng, requests, seconds: float, keep: set,
                 lat[j] = b.t1 - (t0 + requests[j].due)
                 if j in keep:
                     kept[j] = plain_answer(requests[j], res)
-        elif i < len(requests):
-            wait = min(t0 + requests[i].due, end) - time.perf_counter()
+                    kept_writes[j] = writer.applied
+        elif i < len(requests) or writer.next_due() < seconds:
+            due = min(requests[i].due if i < len(requests) else seconds,
+                      writer.next_due())
+            wait = min(t0 + due, end) - time.perf_counter()
             if wait > 0:
                 with annotate("serve.wait"):
                     time.sleep(wait)
@@ -304,7 +374,7 @@ def serve_window(server, timed, eng, requests, seconds: float, keep: set,
             with annotate("serve.wait"):
                 time.sleep(max(0.0, end - time.perf_counter()))
     clock.on = False
-    return lat, kept, batches, (t0, end), traced
+    return lat, kept, kept_writes, batches, (t0, end), traced
 
 
 # --------------------------------------------------------------------------- #
@@ -314,19 +384,60 @@ def _stage_counts(eng) -> dict:
     fs, ag = eng.filter_stats, eng.agg_stats
     return {"filter_s": fs.seconds.get("filter", 0.0),
             "merge_s": fs.seconds.get("merge", 0.0),
+            # the memtable scans of filters and of the aggregates'
+            # general path
+            "memtable_s": (fs.seconds.get("memtable", 0.0)
+                           + ag.seconds.get("memtable", 0.0)),
             "zone_tiles_total": fs.counts.get("zone_tiles_total", 0),
             "zone_tiles_skipped": fs.counts.get("zone_tiles_skipped", 0),
             "fused_launches": fs.counts.get("fused_launches", 0),
             "agg_launches": ag.counts.get("agg_launches", 0)}
 
 
-def check_answers(data, requests, kept: dict, lat: list) -> dict:
-    """Compare the kept answers with the reference; count the due
-    requests that were never answered."""
+@dataclasses.dataclass
+class Writes:
+    """What a run wrote: the prefill and the steady phase's batches, all
+    applied before the window, then the window's, with the number of
+    them applied before the step that answered each kept request."""
+    prefill: Optional[traffic_mod.WriteBatch] = None
+    steady: Sequence[traffic_mod.WriteBatch] = ()
+    window: Sequence[traffic_mod.WriteBatch] = ()
+    kept: Dict[int, int] = dataclasses.field(default_factory=dict)
+    sorted_keys: Optional[np.ndarray] = None
+
+
+def replay(oracles, kept: dict, writes: Writes):
+    """Walk the kept requests in order of the window batches applied
+    before their step, bringing the references to that state first:
+    the load, the prefill, every steady-phase batch, then the window's
+    in order.  Yields each kept request's index once its state is
+    reached, so one reference per distinct state is built, each from
+    the last."""
+    def apply(b):
+        for o in oracles:
+            o.apply(writes.sorted_keys[b.items], b.ids)
+
+    if writes.prefill is not None:
+        apply(writes.prefill)
+    for b in writes.steady:
+        apply(b)
+    done = 0
+    for j in sorted(kept, key=lambda j: (writes.kept.get(j, 0), j)):
+        while done < writes.kept.get(j, 0):
+            apply(writes.window[done])
+            done += 1
+        yield j
+
+
+def check_answers(data, requests, kept: dict, lat: list,
+                  writes: Optional[Writes] = None) -> dict:
+    """Compare the kept answers with the reference over the load plus
+    exactly the write batches applied before each answer's step; count
+    the due requests that were never answered."""
     oracle = Oracle(data.keys, data.ids, data.vocab)
     wrong = 0
-    for j, got in kept.items():
-        if not same_answer(got, oracle.answer(requests[j])):
+    for j in replay([oracle], kept, writes or Writes()):
+        if not same_answer(kept[j], oracle.answer(requests[j])):
             wrong += 1
     unanswered = sum(1 for x in lat if x is None)
     return {"wrong_answers": wrong, "unanswered": unanswered,
@@ -371,6 +482,16 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
     rng = np.random.default_rng([seed, 2])
     n_keep = min(int(mix["check"]), len(requests))
     keep = set(rng.choice(len(requests), n_keep, replace=False).tolist())
+    writes = Writes()
+    if "writes" in mix:
+        writes.sorted_keys = np.sort(data.keys)
+        ndv = data.vocab.shape[0]
+        writes.prefill = traffic_mod.prefill(
+            mix, prefill_rows(cfg, mix), data.keys.shape[0], ndv, seed)
+        writes.steady = traffic_mod.write_batches(
+            mix, data.keys.shape[0], ndv, seed, float(mix["warm_seconds"]), 5)
+        writes.window = traffic_mod.write_batches(
+            mix, data.keys.shape[0], ndv, seed, seconds, 4)
     spill = tempfile.mkdtemp(prefix="bench_spill_")
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     phases = {"data_s": time.perf_counter() - t_start}
@@ -385,22 +506,34 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
             n_warm = warm_up(server, timed, eng, mix, cfg)
             phases["warm_s"] = time.perf_counter() - t
             phases["warm_compile_s"] = clock.seconds
+            if writes.prefill is not None:
+                t = time.perf_counter()
+                apply_prefill(eng, writes, data.vocab, int(cfg["load_chunk"]))
+                phases["prefill_s"] = time.perf_counter() - t
             t = time.perf_counter()
             steady = traffic_mod.generate(mix, cfg["labels"], seed,
                                           float(mix["warm_seconds"]),
                                           rate_per_s=rate_per_s, stream=3)
             serve_window(server, timed, eng, steady,
-                         float(mix["warm_seconds"]), set(), None, clock)
+                         float(mix["warm_seconds"]), set(), None, clock,
+                         Writer(timed, writes.steady, writes.sorted_keys,
+                                data.vocab))
             phases["steady_s"] = time.perf_counter() - t
             shape = layout(eng)
+            memtables_open = memtable_rows(eng)
             before = _stage_counts(eng)
             setup_s = time.perf_counter() - t_start
+            shape_before = eng.shape_report()
             gc_clock.reset()
             gc_clock.on = True
-            lat, kept, batches, window, traced = serve_window(
-                server, timed, eng, requests, seconds, keep, trace_dir, clock)
+            writer = Writer(timed, writes.window, writes.sorted_keys,
+                            data.vocab)
+            lat, kept, writes.kept, batches, window, traced = serve_window(
+                server, timed, eng, requests, seconds, keep, trace_dir, clock,
+                writer)
             gc_clock.on = False
             after = _stage_counts(eng)
+            memtables = memtable_rows(eng)
             mem = dev.memory_stats() or {}
             shape_report = eng.shape_report()
         finally:
@@ -417,7 +550,7 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
     gc.callbacks.remove(gc_clock._hear)
 
     t = time.perf_counter()
-    checks = check_answers(data, requests, kept, lat)
+    checks = check_answers(data, requests, kept, lat, writes)
     phases["check_s"] = time.perf_counter() - t
     result = {
         "correct": checks["wrong_answers"] == 0 and checks["unanswered"] == 0,
@@ -430,7 +563,7 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
            "after": after, "layout": shape, "trace": reduced,
            "compile_s": clock.seconds, "compile_events": clock.events,
            "device_kind": dev.device_kind, "setup_s": setup_s,
-           "traced": traced}
+           "traced": traced, "writer": writer}
     kind = "per_layer" if trace else "end_to_end"
     metrics = read_metrics(metric_names(bench, cell, kind), ctx)
     device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -456,6 +589,10 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
                       "compile_events": clock.events,
                       "n_flushes": shape_report["n_flushes"],
                       "n_compactions": shape_report["n_compactions"],
+                      "window_flushes": (shape_report["n_flushes"]
+                                         - shape_before["n_flushes"]),
+                      "window_compactions": (shape_report["n_compactions"]
+                                             - shape_before["n_compactions"]),
                       "max_step_ms": (longest.t1 - longest.t0) * 1e3,
                       "max_step_at_s": longest.t0 - window[0],
                       "max_step_cpu_ms": longest.cpu_s * 1e3,
@@ -466,10 +603,15 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
                       "gc_max_ms": gc_clock.longest * 1e3,
                       "gc_full": gc_clock.full,
                       "layout": shape}
+    if writes.window:
+        result["diag"].update(_write_diag(requests, lat, writer, seconds,
+                                          memtables_open, memtables))
+        result["diag"]["prefill_rows"] = (0 if writes.prefill is None else
+                                          int(writes.prefill.items.shape[0]))
     if control:
         # the control's answers in the program's place
         result["control"] = {"wrong_answers": _control_wrong(data, requests,
-                                                             kept),
+                                                             kept, writes),
                              "checked": checks["checked"]}
     result["checks"] = {
         "wrong_answers": {"value": checks["wrong_answers"], "limit": 0},
@@ -480,9 +622,56 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
     return result
 
 
-def _control_wrong(data, requests, kept: dict) -> int:
+def apply_prefill(eng, writes: Writes, vocab: np.ndarray,
+                  chunk: int) -> None:
+    """The prefill's updates through ``put_batch``, in order, ``chunk``
+    rows a call."""
+    b = writes.prefill
+    keys, values = writes.sorted_keys[b.items], vocab[b.ids]
+    for lo in range(0, keys.shape[0], chunk):
+        eng.put_batch(keys[lo:lo + chunk], values[lo:lo + chunk])
+    eng.raise_maintenance_errors()
+
+
+def memtable_rows(eng) -> List[int]:
+    """Versions each shard's memtables hold."""
+    return [sum(m.n_versions for m in snap.mems)
+            for snap in eng.snapshot().snaps]
+
+
+def _write_diag(requests, lat, writer: Writer, seconds: float,
+                memtables_open, memtables) -> dict:
+    """Whether the window sustained its load: the write delays and the
+    scan tail in each fifth of it, and what the memtables hold
+    (versions, per shard) when it opens and when it closes."""
+    due = np.asarray([r.due for r in requests])
+    answered = np.asarray([x is not None for x in lat])
+    ms = np.asarray([x if x is not None else np.nan for x in lat]) * 1e3
+    wdue = np.asarray([b.due for b in writer.batches[:writer.applied]])
+    delay = np.asarray(writer.delay_s) * 1e3
+    rows = sum(b.items.shape[0] for b in writer.batches[:writer.applied])
+
+    def part(lo, hi):
+        sel = answered & (due >= lo) & (due < hi)
+        wsel = (wdue >= lo) & (wdue < hi)
+        return (float(np.percentile(ms[sel], 95)) if sel.any() else None,
+                float(delay[wsel].mean()) if wsel.any() else None)
+
+    edges = np.linspace(0.0, seconds, 6)
+    parts = [part(lo, hi) for lo, hi in zip(edges[:-1], edges[1:])]
+    return {"write_batches": writer.applied, "write_rows": rows,
+            "write_delay_mean_ms": float(delay.mean()) if rows else None,
+            "write_delay_max_ms": float(delay.max()) if rows else None,
+            "write_delay_fifths_ms": [d for _, d in parts],
+            "scan_p95_fifths_ms": [p for p, _ in parts],
+            "memtable_rows_open": memtables_open,
+            "memtable_rows": memtables}
+
+
+def _control_wrong(data, requests, kept: dict,
+                   writes: Optional[Writes] = None) -> int:
     ref = Oracle(data.keys, data.ids, data.vocab)
     ctl = Oracle(data.keys, data.ids, data.vocab, truncate=8)
-    return sum(1 for j in kept
+    return sum(1 for j in replay([ref, ctl], kept, writes or Writes())
                if not same_answer(ctl.answer(requests[j]),
                                   ref.answer(requests[j])))

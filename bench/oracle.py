@@ -1,7 +1,8 @@
 """The plain reference: last write wins per key, then byte-string
 predicates and aggregates over the surviving values, in numpy.
 
-It imports nothing of the program and reads only the generated records.
+It imports nothing of the program and reads only the generated records
+and updates (``apply``: later writes to loaded keys, in order).
 Values are held as ids into the vocabulary, so a predicate is evaluated
 once per distinct value and gathered per row.  Semantics, as the store
 states them:
@@ -57,6 +58,18 @@ class Oracle:
             vocab.astype(f"S{truncate}").astype(f"S{self.width}")
         self._order = np.argsort(self.seen, kind="stable")
         self._weights = None
+
+    def apply(self, keys: np.ndarray, ids: np.ndarray) -> None:
+        """Updates of held keys to ``vocab[ids]``, in order and after
+        every record already held: last write wins per key, as in the
+        constructor."""
+        n = keys.shape[0]
+        uniq, first_rev = np.unique(keys[::-1], return_index=True)
+        pos = np.minimum(np.searchsorted(self.keys, uniq),
+                         self.keys.shape[0] - 1)
+        if not np.array_equal(self.keys[pos], uniq):
+            raise ValueError("an update to a key the reference does not hold")
+        self.ids[pos] = ids[n - 1 - first_rev]
 
     def _bound(self, b: bytes):
         return np.asarray([b], f"S{self.width}")[0]

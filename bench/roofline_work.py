@@ -5,7 +5,8 @@ width: a run of ``entries`` codes over a dictionary of ``dict_size``
 values needs ceil(log2(dict_size)) bits a code.
 
 - filter launch with K predicates: read every code once, write one
-  result bit per entry per predicate;
+  result bit per entry per predicate (the filters of a step, or its
+  aggregates where they take the filter kernel);
 - aggregate or histogram launch: read every code once.
 
 The kernel's share of its roofline is these bytes over the chip's HBM
@@ -68,7 +69,10 @@ def roofline_pct(ctx, kind: str) -> Optional[float]:
     t_end = ctx["traced"][1]
     batches = [b for b in ctx["batches"] if b.t1 <= t_end]
     if kind == "filter":
+        # aggregates that meet memtable rows leave the aggregate kernels
+        # for filter launches of their own, K = the step's aggregates
         need = sum(b.filter_launches * filter_launch_bytes(runs, b.n_filters)
+                   + b.agg_filter_launches * filter_launch_bytes(runs, b.n_aggs)
                    for b in batches)
     else:
         need = sum(b.agg_launches for b in batches) * agg_launch_bytes(runs)

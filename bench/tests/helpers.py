@@ -11,9 +11,17 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 SEED = 2**31 + 11   # seeds may exceed what 32 signed bits hold
 
 
+# at the rehearsal size the prefill is a few thousand updates, not the
+# hundreds of thousands a half-full 64 MiB write buffer holds
+PREFILL_SHARE = 0.002
+
+
 def rehearsal(workload: str):
     cell, cfg, mix = harness.find_cell(BENCH, workload)
-    return cell, {**cfg, **cfg["rehearsal"]}, {**mix, "warm_seconds": 0.5}
+    mix = {**mix, "warm_seconds": 0.5}
+    if "writes" in mix:
+        mix["writes"] = {**mix["writes"], "prefill_share": PREFILL_SHARE}
+    return cell, {**cfg, **cfg["rehearsal"]}, mix
 
 
 def run(workload: str, seed: int = SEED, seconds: float = 2.0,

@@ -56,10 +56,12 @@ def test_layout_same_for_two_seeds(workload, tmp_path):
 @pytest.mark.parametrize("workload", CELLS)
 def test_requests_same_sizes_for_every_seed(workload):
     _, cfg, mix = rehearsal(workload)
-    a = traffic.generate(mix, cfg["labels"], SEED, 5.0)
-    b = traffic.generate(mix, cfg["labels"], 7, 5.0)
-    assert [r.due for r in a] == [r.due for r in b]
     block = sum(int(s["count"]) for s in mix["block"])
+    # long enough for two whole blocks at the mix's rate
+    seconds = 2.5 * block / mix["arrivals"]["rate_per_s"]
+    a = traffic.generate(mix, cfg["labels"], SEED, seconds)
+    b = traffic.generate(mix, cfg["labels"], 7, seconds)
+    assert [r.due for r in a] == [r.due for r in b]
     full = len(a) // block * block  # whole blocks: the same multiset
 
     def sizes(reqs):
@@ -67,3 +69,31 @@ def test_requests_same_sizes_for_every_seed(workload):
     assert full and sizes(a[:full]) == sizes(b[:full])
     assert a != b
 
+
+@pytest.mark.parametrize("workload", [w for w in CELLS
+                                      if "writes" in rehearsal(w)[2]])
+def test_write_cell_reads_its_writes(workload):
+    res = run(workload, trace=True)
+    assert res["correct"], res["checks"]
+    assert res["metrics"]["write.put_batch_ms"]["value"] > 0
+    assert res["metrics"]["exec.memtable_ms"]["value"] > 0
+    diag = res["diag"]
+    assert diag["write_batches"] > 0
+    assert diag["window_flushes"] == 0 and diag["window_compactions"] == 0
+    # the window opens on memtables that hold the prefill and the
+    # steady phase's writes, and closes on more
+    _, cfg, mix = rehearsal(workload)
+    assert diag["prefill_rows"] == harness.prefill_rows(cfg, mix) > 0
+    assert sum(diag["memtable_rows_open"]) > diag["prefill_rows"]
+    assert (sum(diag["memtable_rows"]) - sum(diag["memtable_rows_open"])
+            == diag["write_rows"])
+    assert "write_batches" not in run("ycsb_v128.scan_agg")["diag"]
+
+
+def test_prefill_fills_half_the_write_buffer():
+    _, cfg, mix = harness.find_cell(BENCH, "ycsb_v128.htap")
+    share = mix["writes"]["prefill_share"]
+    per_row = cfg["store"]["key_bytes"] + 8 + cfg["value_width"]
+    want = round(share * cfg["store"]["file_bytes"] / per_row) * cfg["shards"]
+    assert share == 0.5 and harness.prefill_rows(cfg, mix) == want
+    assert harness.prefill_rows(cfg, {"block": []}) == 0

@@ -7,16 +7,22 @@
   where it is produced, half of each batch left out, one shard's part
   left out of the gather, one shard's aggregate partial left out of the
   merge.  Each must turn ``correct`` false.
+- In a cell that writes, two more: the scans skip the memtables, and
+  the merge ignores what the memtables shadow (stale run values come
+  back).
 """
 
 import numpy as np
 import pytest
 
+import repro.core.filter_exec as filter_exec_mod
 import repro.core.lsm as lsm_mod
 import repro.query as query_mod
+import repro.query.executor as agg_exec_mod
 from bench import harness, traffic
 from bench.oracle import Oracle, same_answer
 from bench.tests.helpers import CELLS, SEED, rehearsal, run
+from bench.tests.test_traffic import WRITE_CELLS
 from repro.core.filter_exec import FilterResult
 from repro.shard.sharded_lsm import ShardedLSM
 
@@ -88,3 +94,35 @@ def test_fault_turns_correct_false(workload, fault, monkeypatch):
     res = run(workload, seconds=1.5, rate_per_s=200.0)
     assert res["correct"] is False
     assert res["checks"]["wrong_answers"]["value"] > 0
+
+
+def _no_memtable_rows(orig):
+    def visible(mems, snap, value_width=None):
+        return orig([], snap, value_width)
+    return visible
+
+
+def _no_shadowing(orig):
+    def newest(mems, snap):
+        return None
+    return newest
+
+
+# each planted where the filter and the aggregate executors both call it
+WRITE_FAULTS = {
+    "memtable_skipped": ("_memtable_visible", _no_memtable_rows),
+    "shadowing_ignored": ("_memtable_newest", _no_shadowing),
+}
+
+
+@pytest.mark.parametrize("workload", WRITE_CELLS)
+@pytest.mark.parametrize("fault", sorted(WRITE_FAULTS))
+def test_write_fault_turns_correct_false(workload, fault, monkeypatch):
+    name, make = WRITE_FAULTS[fault]
+    for owner in (filter_exec_mod, agg_exec_mod):
+        monkeypatch.setattr(owner, name, make(getattr(owner, name)))
+    monkeypatch.setattr(harness, "warm_up", lambda *a, **kw: 0)
+    res = run(workload, seconds=1.5, rate_per_s=200.0)
+    assert res["correct"] is False
+    assert res["checks"]["wrong_answers"]["value"] > 0
+    assert res["diag"]["write_batches"] > 0

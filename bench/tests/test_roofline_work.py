@@ -35,6 +35,7 @@ def test_shards_of_different_sizes_give_no_per_launch_bytes():
 def test_roofline_share_and_unknown_device():
     class B:  # one batch: 4 filter launches of K = 12, 8 agg launches
         t1, filter_launches, agg_launches, n_filters = 1.0, 4, 8, 12
+        agg_filter_launches, n_aggs = 0, 4
     need = 4 * rw.filter_launch_bytes([(750_000, 30_000)], 12)
     ctx = {"trace": {"modules": {"jit_fused_zone_filter_2d": need / 819e9 * 2,
                                  "jit_fused_zone_agg_2d": 1.0}},

@@ -18,6 +18,23 @@ A mix (``bench/traffic/<name>.json``) is data only:
 ``warm_seconds`` how long set-up serves the same mix (another seed
                stream) before the window, so the window meets a server
                in its steady state.
+``writes``     optional: ``{"kind": "ycsb_update", "rate_per_s": r,
+               "batch": b, "distribution": "zipfian", "theta": t,
+               "prefill_share": f}``.  ``kind`` and ``distribution``
+               are format tags: the generator knows only these two and
+               refuses any other.  One ``put_batch`` of b updates falls
+               due every b / r seconds, the same times for every seed.
+               Each update overwrites a loaded key drawn by YCSB's
+               scrambled zipfian (``workloads/workloada``: ranks over
+               10**10 items with constant t, FNV-1a-hashed onto the
+               loaded keys, so the hot keys spread over the key space)
+               with a value drawn uniformly from the configuration's
+               vocabulary.  Before the steady phase, set-up applies
+               the same stream's updates, untimed, until the memtables
+               hold f of their flush size (``prefill``), the mean
+               occupancy of a store written at a steady rate.  Writes
+               draw from seed streams of their own, so a mix without
+               them yields exactly the requests it yields with them.
 
 Labels are the configuration's ordered value labels
 (``cfg["labels"]``: a ``%``-format and a count); a range over labels
@@ -98,6 +115,103 @@ def generate(mix: dict, labels: dict, seed: int, seconds: float,
             if len(out) == n_req:
                 break
     return [r for r in out if r.due < seconds]
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class WriteBatch:
+    due: float          # seconds after window start
+    items: np.ndarray   # int64 [batch]: index into the sorted loaded keys
+    ids: np.ndarray     # int32 [batch]: value = vocab[ids]
+
+
+# YCSB's ScrambledZipfianGenerator: a zipfian over ITEM_COUNT ranks,
+# each rank hashed (fnvhash64) onto the items
+YCSB_ITEM_COUNT = 10**10
+FNV_OFFSET_64 = 0xCBF29CE484222325
+FNV_PRIME_64 = 1099511628211
+
+
+ZETA_EXACT_TERMS = 10**6
+
+
+def zeta(n: int, theta: float) -> float:
+    """sum_{i=1..n} i**-theta: the first ``ZETA_EXACT_TERMS`` terms
+    summed, the rest by Euler-Maclaurin (YCSB precomputes
+    26.46902820178302 for 10**10 items at 0.99)."""
+    m = min(n, ZETA_EXACT_TERMS)
+    head = float(np.sum(np.arange(1, m + 1, dtype=np.float64) ** -theta))
+    if n == m:
+        return head
+    a, b = float(m), float(n)
+    tail = (b ** (1 - theta) - a ** (1 - theta)) / (1 - theta)
+    tail += (b ** -theta - a ** -theta) / 2
+    tail += theta * (a ** (-theta - 1) - b ** (-theta - 1)) / 12
+    return head + tail
+
+
+def zipfian_ranks(rng, n: int, theta: float, items: int) -> np.ndarray:
+    """``n`` draws of YCSB's ZipfianGenerator over [0, items) (Gray et
+    al.'s closed form, as ``ZipfianGenerator.nextLong``)."""
+    zetan = zeta(items, theta)
+    zeta2 = 1.0 + 0.5 ** theta
+    alpha = 1.0 / (1.0 - theta)
+    eta = (1.0 - (2.0 / items) ** (1.0 - theta)) / (1.0 - zeta2 / zetan)
+    u = rng.random(n)
+    uz = u * zetan
+    ranks = (items * (eta * u - eta + 1.0) ** alpha).astype(np.int64)
+    ranks = np.where(uz < 1.0 + 0.5 ** theta, 1, ranks)
+    return np.where(uz < 1.0, 0, ranks)
+
+
+def fnvhash64(x: np.ndarray) -> np.ndarray:
+    """YCSB's ``Utils.fnvhash64``: FNV-1a over the 8 bytes of a long,
+    low byte first, then the absolute value as a signed long."""
+    val = x.astype(np.uint64)
+    h = np.full(val.shape, FNV_OFFSET_64, np.uint64)
+    for _ in range(8):
+        h ^= val & np.uint64(0xFF)
+        val >>= np.uint64(8)
+        h *= np.uint64(FNV_PRIME_64)
+    return np.abs(h.view(np.int64))
+
+
+def write_batches(mix: dict, n_items: int, ndv: int, seed: int,
+                  seconds: float, stream: int) -> List[WriteBatch]:
+    """The mix's write batches due in [0, seconds), in due order; none
+    when the mix has no ``writes``."""
+    w = mix.get("writes")
+    if w is None:
+        return []
+    size = int(w["batch"])
+    period = size / float(w["rate_per_s"])
+    n = int(np.ceil(seconds / period))
+    due = np.arange(n) * period
+    n = int(np.count_nonzero(due < seconds))
+    items, ids = _updates(w, n * size, n_items, ndv, seed, stream)
+    return [WriteBatch(float(due[i]), items[i * size:(i + 1) * size],
+                       ids[i * size:(i + 1) * size]) for i in range(n)]
+
+
+def prefill(mix: dict, rows: int, n_items: int, ndv: int,
+            seed: int) -> Optional[WriteBatch]:
+    """``rows`` updates of the mix's write stream (seed stream 6), all
+    due before the steady phase; none without ``writes``."""
+    w = mix.get("writes")
+    if w is None or rows <= 0:
+        return None
+    items, ids = _updates(w, rows, n_items, ndv, seed, 6)
+    return WriteBatch(0.0, items, ids)
+
+
+def _updates(w: dict, n: int, n_items: int, ndv: int, seed: int,
+             stream: int) -> Tuple[np.ndarray, np.ndarray]:
+    if w["kind"] != "ycsb_update" or w["distribution"] != "zipfian":
+        raise ValueError(f"unknown writes {w['kind']!r} / "
+                         f"{w['distribution']!r}")
+    rng = np.random.default_rng([seed, stream])
+    ranks = zipfian_ranks(rng, n, float(w["theta"]), YCSB_ITEM_COUNT)
+    items = fnvhash64(ranks) % n_items
+    return items, rng.integers(0, ndv, n).astype(np.int32)
 
 
 def warmup_batches(mix: dict, labels: dict,
